@@ -237,6 +237,9 @@ def run(fast: bool = True, json_path: str = "BENCH_slo.json",
     if SRC not in sys.path:  # direct `python benchmarks/chaos_bench.py` runs
         sys.path.insert(0, SRC)
     ticks = 48 if fast else 96
+    print("chaos: CPU-only recovery gate, run on 8 virtual CPU devices "
+          "(JAX_PLATFORMS=cpu); none of its numbers is a chip result",
+          flush=True)
     env = dict(os.environ,
                PYTHONPATH=SRC + os.pathsep + os.environ.get("PYTHONPATH", ""),
                XLA_FLAGS="--xla_force_host_platform_device_count=8",

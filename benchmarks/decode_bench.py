@@ -74,15 +74,18 @@ def bench_decode_attention(full: bool, iters: int):
         key = jax.random.PRNGKey(0)
         Smax = cache_len
         q = jax.random.normal(key, (B, 1, H, hd))
-        k = jax.random.normal(jax.random.fold_in(key, 1), (B, Smax, K, hd))
-        v = jax.random.normal(jax.random.fold_in(key, 2), (B, Smax, K, hd))
+        # head-major cache, as the model keeps it; the naive oracle reads
+        # the sequence-major view
+        k = jax.random.normal(jax.random.fold_in(key, 1), (B, K, Smax, hd))
+        v = jax.random.normal(jax.random.fold_in(key, 2), (B, K, Smax, hd))
         # ragged: half the slots at full length, half at half length
         lengths = jnp.asarray([Smax if b % 2 == 0 else Smax // 2
                                for b in range(B)], jnp.int32)
 
         fns = {
             "naive": jax.jit(lambda q, k, v, ln: naive_attention(
-                q, k, v, causal=False, kv_len=ln)),
+                q, k.swapaxes(1, 2), v.swapaxes(1, 2), causal=False,
+                kv_len=ln)),
             "pallas": jax.jit(lambda q, k, v, ln: kops.decode_attention(
                 q, k, v, ln)),
         }

@@ -96,13 +96,18 @@ class Rung:
     def jitted_step(self, cfg, optimizer, *, lr: float = 0.05,
                     compressor=None):
         """Lazily-compiled cached jitted step: first call on a rung compiles,
-        later calls (including after migrating away and back) reuse it."""
+        later calls (including after migrating away and back) reuse it.
+
+        The state is donated: the new parameters and optimizer moments
+        reuse the old buffers instead of doubling the state's footprint.
+        Callers must keep only the returned state."""
         key = (cfg.name, self.signature(), optimizer.name, float(lr),
                getattr(compressor, "scheme", self.compression))
         if self._jitted is None or self._jitted_key != key:
             model = self.build_model(cfg)
             self._jitted = jax.jit(self.train_step_fn(
-                model, optimizer, lr=lr, compressor=compressor))
+                model, optimizer, lr=lr, compressor=compressor),
+                donate_argnums=0)
             self._jitted_key = key
         return self._jitted
 

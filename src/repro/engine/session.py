@@ -40,7 +40,6 @@ import numpy as np
 
 from repro import obs
 from repro.checkpoint.manager import CheckpointManager, shard_restore
-from repro.compat import set_mesh
 from repro.core.controller import SwanController
 from repro.engine.events import InterferenceTrace
 from repro.engine.jobs import SocJob, StepReport
@@ -140,7 +139,7 @@ class TrainSession(SocJob):
                 shape = rung.mesh_shape
             return self.elastic.make_mesh(shape=shape)
         if rung.mesh_shape is not None:
-            from repro.compat import make_mesh
+            from repro.launch.mesh import make_mesh
             names = ("pod", "data", "model")[-len(rung.mesh_shape):]
             return make_mesh(rung.mesh_shape, names)
         return None
@@ -156,7 +155,7 @@ class TrainSession(SocJob):
         fn = self.rung.jitted_step(self.cfg, self.optimizer, lr=self.lr,
                                    compressor=self.compressor)
         if self._mesh is not None:
-            with set_mesh(self._mesh):
+            with jax.set_mesh(self._mesh):
                 return fn(state, batch)
         return fn(state, batch)
 

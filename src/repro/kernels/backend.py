@@ -77,15 +77,3 @@ def divisor_block(size: int, preferred: int) -> int:
     while size % b:
         b -= 1
     return b
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (new JAX) / ``pltpu.TPUCompilerParams`` (0.4.x).
-
-    The class was renamed between releases; this must track repro.compat's
-    version span or the real-TPU (interpret=False) path dies on import of
-    whichever name the install lacks.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)

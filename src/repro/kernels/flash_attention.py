@@ -16,9 +16,11 @@ of one query block. KV streams through the grid via BlockSpec index maps in
 (block_k, hd) tiles: per grid step the kernel holds one (block_q, hd) query
 tile and one (block_k, hd) KV tile, and the (Sq, Sk) score matrix never
 exists anywhere — not in HBM, not in VMEM. The forward additionally emits the
-log-sum-exp rows ``lse = m + log(l)`` of shape (B, H, Sq); together with the
-saved output ``o`` this is the entire backward residual, O(B*H*Sq) instead of
-the O(Sq*Sk) probability matrix.
+log-sum-exp rows ``lse = m + log(l)``; the backward keeps them as (B, H, Sq)
+and, with the saved output ``o``, that is the entire residual, O(B*H*Sq)
+instead of the O(Sq*Sk) probability matrix. Mosaic tiles a block's last two
+dims by (8, 128), so inside the kernels the per-row statistics (lse, and
+the backward's ``delta``) travel lane-broadcast as (B, H, Sq, 128).
 
 Causal block skipping
 ---------------------
@@ -57,7 +59,7 @@ The MLA variant runs in the latent space (k = [latent | k_rope], v = latent)
 via the same kernel with K=1, G=H and an explicit softmax scale.
 
 ``flash_decode_paged`` is the paged-KV sibling: the cache lives in a
-``(num_blocks, block_size, K, hd)`` pool shared by every sequence and each
+``(num_blocks, K, block_size, hd)`` pool shared by every sequence and each
 sequence names its blocks via a ``(B, max_blocks_per_seq)`` int32 block
 table. The table rides scalar prefetch next to ``lengths``, so the KV
 BlockSpec index maps translate logical tile j -> physical block
@@ -82,11 +84,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.backend import (attn_bf16, divisor_block,
-                                   resolve_interpret, tpu_compiler_params)
+from repro.kernels.backend import attn_bf16, divisor_block, resolve_interpret
 
 NEG_INF = -1e30
-_LANES = 128  # TPU lane width: m/l scratch rides (block_q, 128)
+_LANES = 128  # TPU lane width: m/l scratch and lse/delta rows ride (bq, 128)
 
 
 def _causal_mask(s, qi, ji, bq, bk, q_offset):
@@ -99,7 +100,7 @@ def _grid_params(interpret: bool):
     """dimension_semantics: batch/head/outer-block parallel, inner sequential."""
     if interpret:
         return {}
-    return {"compiler_params": tpu_compiler_params(
+    return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))}
 
 
@@ -155,7 +156,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         m = m_scr[:, :1]
         l = jnp.maximum(l_scr[:, :1], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
+        lse_ref[0, 0] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape[2:])
 
 
 def _fwd_call(q, k, v, *, causal: bool, q_offset: int, bq: int, bk: int,
@@ -181,11 +182,11 @@ def _fwd_call(q, k, v, *, causal: bool, q_offset: int, bq: int, bk: int,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, _LANES), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sq, _LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, _LANES), jnp.float32),
@@ -224,8 +225,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0, 0].astype(cdt)
         v = v_ref[0, 0].astype(cdt)
         do = do_ref[0, 0].astype(cdt)
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
+        lse = lse_ref[0, 0][:, :1]
+        delta = delta_ref[0, 0][:, :1]
         s = scale * jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                         preferred_element_type=jnp.float32)
         p = jnp.exp(s - lse)
@@ -270,8 +271,8 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, dk_ref,
         k = k_ref[0, 0].astype(cdt)
         v = v_ref[0, 0].astype(cdt)
         do = do_ref[0, 0].astype(cdt)
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
+        lse = lse_ref[0, 0][:, :1]
+        delta = delta_ref[0, 0][:, :1]
         s = scale * jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                         preferred_element_type=jnp.float32)
         p = jnp.exp(s - lse)
@@ -299,7 +300,13 @@ def _bwd_call(q, k, v, o, lse, do, *, causal: bool, q_offset: int, bq: int,
     Sk = k.shape[2]
     n_q, n_kv = Sq // bq, Sk // bk
     scale = 1.0 / math.sqrt(hd)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    # Mosaic tiles a block's last two dims by (8, 128): per-row statistics
+    # ride lane-broadcast (bq, 128) blocks, as the m/l scratch does
+    lanes = (B, H, Sq, _LANES)
+    lse = jnp.broadcast_to(lse[..., None], lanes)
+    delta = jnp.broadcast_to(
+        jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                keepdims=True), lanes)
 
     def kv_index(b, h, i, j):
         if causal:
@@ -315,8 +322,8 @@ def _bwd_call(q, k, v, o, lse, do, *, causal: bool, q_offset: int, bq: int,
             pl.BlockSpec((1, 1, bk, hd), kv_index),
             pl.BlockSpec((1, 1, bk, hd), kv_index),
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, _LANES), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, _LANES), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), jnp.float32),
@@ -331,10 +338,6 @@ def _bwd_call(q, k, v, o, lse, do, *, causal: bool, q_offset: int, bq: int,
             i = jnp.clip(i, 0, n_q - 1)
         return (b, h, i, 0)
 
-    def q_row_index(b, h, j, i):
-        bidx = q_index(b, h, j, i)
-        return (bidx[0], bidx[1], bidx[2])
-
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, block_q=bq, block_k=bk, causal=causal,
                           q_offset=q_offset, scale=scale, n_q=n_q, lowp=lowp),
@@ -344,8 +347,8 @@ def _bwd_call(q, k, v, o, lse, do, *, causal: bool, q_offset: int, bq: int,
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, j, i: (b, h, j, 0)),
             pl.BlockSpec((1, 1, bq, hd), q_index),
             pl.BlockSpec((1, 1, bq, hd), q_index),
-            pl.BlockSpec((1, 1, bq), q_row_index),
-            pl.BlockSpec((1, 1, bq), q_row_index),
+            pl.BlockSpec((1, 1, bq, _LANES), q_index),
+            pl.BlockSpec((1, 1, bq, _LANES), q_index),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, j, i: (b, h, j, 0)),
@@ -378,7 +381,7 @@ def _flash_mha(q, k, v, causal, q_offset, bq, bk, interpret, lowp):
 def _flash_mha_fwd(q, k, v, causal, q_offset, bq, bk, interpret, lowp):
     o, lse = _fwd_call(q, k, v, causal=causal, q_offset=q_offset, bq=bq, bk=bk,
                        interpret=interpret, lowp=lowp)
-    return o, (q, k, v, o, lse)
+    return o, (q, k, v, o, lse[..., 0])
 
 
 def _flash_mha_bwd(causal, q_offset, bq, bk, interpret, lowp, res, do):
@@ -423,11 +426,12 @@ def flash_attention_fwd_lse(q, k, v, *, causal: bool = True, q_offset: int = 0,
                             lowp: Optional[bool] = None):
     """Forward that also returns the (B,H,Sq) log-sum-exp residual rows."""
     Sq, Sk = q.shape[2], k.shape[2]
-    return _fwd_call(q, k, v, causal=causal, q_offset=q_offset,
-                     bq=divisor_block(Sq, block_q),
-                     bk=divisor_block(Sk, block_k),
-                     interpret=resolve_interpret(interpret),
-                     lowp=attn_bf16(lowp))
+    o, lse = _fwd_call(q, k, v, causal=causal, q_offset=q_offset,
+                       bq=divisor_block(Sq, block_q),
+                       bk=divisor_block(Sk, block_k),
+                       interpret=resolve_interpret(interpret),
+                       lowp=attn_bf16(lowp))
+    return o, lse[..., 0]
 
 
 # ---------------------------------------------------------------------------
@@ -451,8 +455,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     def _():
         cdt = jnp.bfloat16 if lowp else jnp.float32
         q = (q_ref[0, 0].astype(jnp.float32) * scale).astype(cdt)  # (G, hd)
-        k = k_ref[0, :, 0].astype(cdt)                             # (bk, hd)
-        v = v_ref[0, :, 0].astype(cdt)                             # (bk, hdv)
+        k = k_ref[0, 0].astype(cdt)                                # (bk, hd)
+        v = v_ref[0, 0].astype(cdt)                                # (bk, hdv)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         kv_idx = ji * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -478,7 +482,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 def _decode_grid_params(interpret: bool):
     if interpret:
         return {}
-    return {"compiler_params": tpu_compiler_params(
+    return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))}
 
 
@@ -489,7 +493,8 @@ def flash_decode(q, k, v, lengths, *, scale: Optional[float] = None,
 
     q: (B, K, G, hd) — one new token's query heads, grouped so the G query
        heads sharing KV head k sit together (GQA: G = H // K; MHA: G = 1).
-    k: (B, Smax, K, hd)   v: (B, Smax, K, hdv) — the KV cache buffers.
+    k: (B, K, Smax, hd)   v: (B, K, Smax, hdv) — the head-major KV cache
+       buffers, so each KV tile is a (bk, hd) slab as Mosaic tiles it.
     lengths: (B,) int32 — row b attends to cache positions < lengths[b];
        rows with length 0 (idle serving slots) produce zeros, not NaNs.
 
@@ -500,7 +505,7 @@ def flash_decode(q, k, v, lengths, *, scale: Optional[float] = None,
     Serving path only: no custom_vjp (decode never backpropagates).
     """
     B, K, G, hd = q.shape
-    Smax = k.shape[1]
+    Smax = k.shape[2]
     hdv = v.shape[-1]
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
@@ -516,15 +521,15 @@ def flash_decode(q, k, v, lengths, *, scale: Optional[float] = None,
         # clamp dead tiles past lengths[b] to the last live one: the pipeline
         # re-fetches a resident block instead of DMA-ing cache it won't read
         j = jnp.minimum(j, jnp.maximum(pl.cdiv(len_ref[b], bk) - 1, 0))
-        return (b, j, kh, 0)
+        return (b, kh, j, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, K, n_kv),
         in_specs=[
             pl.BlockSpec((1, 1, G, hd), q_index),
-            pl.BlockSpec((1, bk, 1, hd), kv_index),
-            pl.BlockSpec((1, bk, 1, hdv), kv_index),
+            pl.BlockSpec((1, 1, bk, hd), kv_index),
+            pl.BlockSpec((1, 1, bk, hdv), kv_index),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hdv), q_index),
         scratch_shapes=[
@@ -563,8 +568,9 @@ def flash_decode_paged(q, k_pool, v_pool, block_table, lengths, *,
     """Single-query flash decode over a paged (block-pooled) KV cache.
 
     q: (B, K, G, hd) — grouped query heads, as in ``flash_decode``.
-    k_pool: (num_blocks, block_size, K, hd)  v_pool: (..., hdv) — physical
-       KV blocks shared by all sequences (no batch dimension).
+    k_pool: (num_blocks, K, block_size, hd)  v_pool: (..., hdv) — physical
+       KV blocks shared by all sequences (no batch dimension), head-major
+       inside a block.
     block_table: (B, T) int32 — logical block j of sequence b lives in
        physical block ``block_table[b, j]``; rows may point unused tail
        entries at any valid block (they are clamped and predicated away).
@@ -580,7 +586,7 @@ def flash_decode_paged(q, k_pool, v_pool, block_table, lengths, *,
     cache the table describes. Returns (B, K, G, hdv).
     """
     B, K, G, hd = q.shape
-    num_blocks, bs = k_pool.shape[0], k_pool.shape[1]
+    num_blocks, bs = k_pool.shape[0], k_pool.shape[2]
     hdv = v_pool.shape[-1]
     T = block_table.shape[1]
     if scale is None:
@@ -596,15 +602,15 @@ def flash_decode_paged(q, k_pool, v_pool, block_table, lengths, *,
         # clamp dead tiles past lengths[b] to the last live logical block,
         # then translate logical -> physical through the block table
         j = jnp.minimum(j, jnp.maximum(pl.cdiv(len_ref[b], bs) - 1, 0))
-        return (tbl_ref[b, j], 0, kh, 0)
+        return (tbl_ref[b, j], kh, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, K, T),
         in_specs=[
             pl.BlockSpec((1, 1, G, hd), q_index),
-            pl.BlockSpec((1, bs, 1, hd), kv_index),
-            pl.BlockSpec((1, bs, 1, hdv), kv_index),
+            pl.BlockSpec((1, 1, bs, hd), kv_index),
+            pl.BlockSpec((1, 1, bs, hdv), kv_index),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hdv), q_index),
         scratch_shapes=[
@@ -634,12 +640,13 @@ def _spec_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     """The single-query decode kernel grown to a q-block of draft positions.
 
     The q block holds the S = n_draft draft positions of one (batch, KV
-    head) program, flattened together with the G-row query group to
-    (S*G, hd) rows where row r = qi*G + g. ``len_ref[b]`` is the BASE cache
-    length — the valid count *before* the draft KVs were scattered at
-    positions base..base+S-1 — so draft position qi attends cache positions
-    < base + qi + 1: the per-row causal mask lives inside the tile, and the
-    online-softmax carry is per row exactly as in ``_decode_kernel``.
+    head) program, flattened (by the wrapper, outside the kernel) together
+    with the G-row query group to (S*G, hd) rows where row r = qi*G + g.
+    ``len_ref[b]`` is the BASE cache length — the valid count *before* the
+    draft KVs were scattered at positions base..base+S-1 — so draft position
+    qi attends cache positions < base + qi + 1: the per-row causal mask
+    lives inside the tile, and the online-softmax carry is per row exactly
+    as in ``_decode_kernel``.
     """
     b, ji = pl.program_id(0), pl.program_id(2)
 
@@ -654,10 +661,9 @@ def _spec_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     @pl.when(ji * block_k < base + n_draft)
     def _():
         cdt = jnp.bfloat16 if lowp else jnp.float32
-        q = q_ref[0, 0].reshape(n_draft * group, q_ref.shape[-1])
-        q = (q.astype(jnp.float32) * scale).astype(cdt)            # (S*G, hd)
-        k = k_ref[0, :, 0].astype(cdt)                             # (bk, hd)
-        v = v_ref[0, :, 0].astype(cdt)                             # (bk, hdv)
+        q = (q_ref[0, 0].astype(jnp.float32) * scale).astype(cdt)  # (S*G, hd)
+        k = k_ref[0, 0].astype(cdt)                                # (bk, hd)
+        v = v_ref[0, 0].astype(cdt)                                # (bk, hdv)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         kv_idx = ji * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -678,8 +684,7 @@ def _spec_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     @pl.when(ji == n_kv - 1)
     def _():
         l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l).reshape(
-            n_draft, group, acc_scr.shape[-1]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def flash_decode_spec(q, k, v, lengths, *, scale: Optional[float] = None,
@@ -689,8 +694,8 @@ def flash_decode_spec(q, k, v, lengths, *, scale: Optional[float] = None,
 
     q: (B, K, S, G, hd) — S draft positions' query heads, grouped per KV
        head as in ``flash_decode`` (GQA: G = H // K).
-    k: (B, Smax, K, hd)   v: (B, Smax, K, hdv) — cache buffers with the S
-       draft tokens' KV already scattered at positions
+    k: (B, K, Smax, hd)   v: (B, K, Smax, hdv) — head-major cache buffers
+       with the S draft tokens' KV already scattered at positions
        lengths[b]..lengths[b]+S-1.
     lengths: (B,) int32 — BASE valid counts (before the drafts); draft
        position qi of row b attends cache positions < lengths[b] + qi + 1.
@@ -701,7 +706,7 @@ def flash_decode_spec(q, k, v, lengths, *, scale: Optional[float] = None,
     tile. Returns (B, K, S, G, hdv). Serving path only (no custom_vjp).
     """
     B, K, S, G, hd = q.shape
-    Smax = k.shape[1]
+    Smax = k.shape[2]
     hdv = v.shape[-1]
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
@@ -711,36 +716,37 @@ def flash_decode_spec(q, k, v, lengths, *, scale: Optional[float] = None,
     interp = resolve_interpret(interpret)
 
     def q_index(b, kh, j, len_ref):
-        return (b, kh, 0, 0, 0)
+        return (b, kh, 0, 0)
 
     def kv_index(b, kh, j, len_ref):
         # the last live tile now covers the drafts too: clamp at base + S
         j = jnp.minimum(j, jnp.maximum(pl.cdiv(len_ref[b] + S, bk) - 1, 0))
-        return (b, j, kh, 0)
+        return (b, kh, j, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, K, n_kv),
         in_specs=[
-            pl.BlockSpec((1, 1, S, G, hd), q_index),
-            pl.BlockSpec((1, bk, 1, hd), kv_index),
-            pl.BlockSpec((1, bk, 1, hdv), kv_index),
+            pl.BlockSpec((1, 1, S * G, hd), q_index),
+            pl.BlockSpec((1, 1, bk, hd), kv_index),
+            pl.BlockSpec((1, 1, bk, hdv), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, 1, S, G, hdv), q_index),
+        out_specs=pl.BlockSpec((1, 1, S * G, hdv), q_index),
         scratch_shapes=[
             pltpu.VMEM((S * G, _LANES), jnp.float32),
             pltpu.VMEM((S * G, _LANES), jnp.float32),
             pltpu.VMEM((S * G, hdv), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_spec_decode_kernel, block_k=bk, scale=scale,
                           n_kv=n_kv, n_draft=S, group=G, lowp=attn_bf16(lowp)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, S, G, hdv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K, S * G, hdv), q.dtype),
         interpret=interp,
         **_decode_grid_params(interp),
-    )(lengths, q, k, v)
+    )(lengths, q.reshape(B, K, S * G, hd), k, v)
+    return out.reshape(B, K, S, G, hdv)
 
 
 def _spec_decode_paged_kernel(len_ref, tbl_ref, *rest, **kw):
@@ -757,7 +763,7 @@ def flash_decode_spec_paged(q, k_pool, v_pool, block_table, lengths, *,
     """Multi-token speculative verify over a paged (block-pooled) KV cache.
 
     q: (B, K, S, G, hd) — S draft positions, grouped as in
-       ``flash_decode_spec``. k_pool/v_pool: (num_blocks, block_size, K, .)
+       ``flash_decode_spec``. k_pool/v_pool: (num_blocks, K, block_size, .)
        physical blocks; block_table: (B, T) int32 with blocks mapped through
        position lengths[b] + S - 1 (the engine appends draft positions before
        the verify call, so boundary blocks already exist).
@@ -767,7 +773,7 @@ def flash_decode_spec_paged(q, k_pool, v_pool, block_table, lengths, *,
     last logical block covering base + S. Returns (B, K, S, G, hdv).
     """
     B, K, S, G, hd = q.shape
-    num_blocks, bs = k_pool.shape[0], k_pool.shape[1]
+    num_blocks, bs = k_pool.shape[0], k_pool.shape[2]
     hdv = v_pool.shape[-1]
     T = block_table.shape[1]
     if scale is None:
@@ -777,32 +783,33 @@ def flash_decode_spec_paged(q, k_pool, v_pool, block_table, lengths, *,
     interp = resolve_interpret(interpret)
 
     def q_index(b, kh, j, len_ref, tbl_ref):
-        return (b, kh, 0, 0, 0)
+        return (b, kh, 0, 0)
 
     def kv_index(b, kh, j, len_ref, tbl_ref):
         j = jnp.minimum(j, jnp.maximum(pl.cdiv(len_ref[b] + S, bs) - 1, 0))
-        return (tbl_ref[b, j], 0, kh, 0)
+        return (tbl_ref[b, j], kh, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, K, T),
         in_specs=[
-            pl.BlockSpec((1, 1, S, G, hd), q_index),
-            pl.BlockSpec((1, bs, 1, hd), kv_index),
-            pl.BlockSpec((1, bs, 1, hdv), kv_index),
+            pl.BlockSpec((1, 1, S * G, hd), q_index),
+            pl.BlockSpec((1, 1, bs, hd), kv_index),
+            pl.BlockSpec((1, 1, bs, hdv), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, 1, S, G, hdv), q_index),
+        out_specs=pl.BlockSpec((1, 1, S * G, hdv), q_index),
         scratch_shapes=[
             pltpu.VMEM((S * G, _LANES), jnp.float32),
             pltpu.VMEM((S * G, _LANES), jnp.float32),
             pltpu.VMEM((S * G, hdv), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_spec_decode_paged_kernel, block_k=bs, scale=scale,
                           n_kv=T, n_draft=S, group=G, lowp=attn_bf16(lowp)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, S, G, hdv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K, S * G, hdv), q.dtype),
         interpret=interp,
         **_decode_grid_params(interp),
-    )(lengths, table, q, k_pool, v_pool)
+    )(lengths, table, q.reshape(B, K, S * G, hd), k_pool, v_pool)
+    return out.reshape(B, K, S, G, hdv)

@@ -60,7 +60,8 @@ def decode_attention(q, k, v, lengths, *, block_k: int = 256):
     """Single-token GQA decode against a ragged KV cache, fused.
 
     q: (B, 1, H, hd) — the new token's queries (cache already updated).
-    k,v: (B, Smax, K, hd) cache buffers; lengths: (B,) or scalar valid counts.
+    k,v: (B, K, Smax, hd) head-major cache buffers; lengths: (B,) or scalar
+    valid counts.
 
     Unlike the prefill wrapper above, the KV heads are NOT broadcast to H —
     the kernel's query block holds the whole (G = H//K) query group, so each
@@ -68,7 +69,7 @@ def decode_attention(q, k, v, lengths, *, block_k: int = 256):
     bytes moved per token drop from H/K x cache to 1 x cache.
     """
     B, _, H, hd = q.shape
-    K = k.shape[2]
+    K = k.shape[1]
     qg = q.reshape(B, K, H // K, hd)  # (B,1,H,hd) -> grouped, same head order
     out = flash_decode(qg, k, v, lengths, block_k=block_k)
     return out.reshape(B, 1, H, v.shape[-1])
@@ -87,22 +88,22 @@ def decode_attention_mla(q_lat, q_rope, latent, k_rope, lengths, *,
     the latent-space context (B, 1, H, r); the caller applies W_UV.
     """
     q = jnp.concatenate([q_lat, q_rope], -1)  # (B, K=1, G=H, r+rd)
-    kv = jnp.concatenate([latent, k_rope.astype(latent.dtype)], -1)[:, :, None]
-    val = latent[:, :, None]
+    kv = jnp.concatenate([latent, k_rope.astype(latent.dtype)], -1)[:, None]
+    val = latent[:, None]
     return flash_decode(q, kv, val, lengths, scale=scale, block_k=block_k)
 
 
 def decode_attention_paged(q, k_pool, v_pool, block_table, lengths):
     """Single-token GQA decode against a paged (block-pooled) KV cache.
 
-    q: (B, 1, H, hd); k_pool/v_pool: (num_blocks, block_size, K, hd[v])
+    q: (B, 1, H, hd); k_pool/v_pool: (num_blocks, K, block_size, hd[v])
     shared physical blocks; block_table: (B, T) int32; lengths: (B,) or
     scalar valid counts. Same grouped-query streaming as
     ``decode_attention``, with the KV index maps going through the
     scalar-prefetched block table.
     """
     B, _, H, hd = q.shape
-    K = k_pool.shape[2]
+    K = k_pool.shape[1]
     qg = q.reshape(B, K, H // K, hd)
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     out = flash_decode_paged(qg, k_pool, v_pool, block_table, lengths)
@@ -113,15 +114,15 @@ def decode_attention_spec(q, k, v, lengths, *, block_k: int = 256):
     """Speculative multi-token GQA verify against a ragged KV cache, fused.
 
     q: (B, S, H, hd) — the S draft positions' queries (draft KVs already
-    scattered at positions lengths[b]..lengths[b]+S-1). k,v: (B, Smax, K,
-    hd[v]) cache buffers; lengths: (B,) or scalar BASE valid counts (before
-    the drafts). Draft position qi attends cache positions
+    scattered at positions lengths[b]..lengths[b]+S-1). k,v: (B, K, Smax,
+    hd[v]) head-major cache buffers; lengths: (B,) or scalar BASE valid
+    counts (before the drafts). Draft position qi attends cache positions
     < lengths[b] + qi + 1 — causal inside the verify tile. One kernel call
     verifies all S positions; the cache bytes are still streamed once per KV
     head, amortized over S tokens instead of one.
     """
     B, S, H, hd = q.shape
-    K = k.shape[2]
+    K = k.shape[1]
     # (B,S,H,hd) -> (B,K,S,G,hd): group the G heads sharing each KV head,
     # keeping draft order explicit so the kernel maps row r -> qi = r // G
     qg = q.reshape(B, S, K, H // K, hd).transpose(0, 2, 1, 3, 4)
@@ -137,7 +138,7 @@ def decode_attention_spec_paged(q, k_pool, v_pool, block_table, lengths):
     draft positions (boundary blocks appended before the verify call).
     """
     B, S, H, hd = q.shape
-    K = k_pool.shape[2]
+    K = k_pool.shape[1]
     qg = q.reshape(B, S, K, H // K, hd).transpose(0, 2, 1, 3, 4)
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     out = flash_decode_spec_paged(qg, k_pool, v_pool, block_table, lengths)
@@ -155,7 +156,7 @@ def decode_attention_mla_paged(q_lat, q_rope, latent_pool, k_rope_pool,
     B = q_lat.shape[0]
     q = jnp.concatenate([q_lat, q_rope], -1)  # (B, K=1, G=H, r+rd)
     kv = jnp.concatenate(
-        [latent_pool, k_rope_pool.astype(latent_pool.dtype)], -1)[:, :, None]
-    val = latent_pool[:, :, None]
+        [latent_pool, k_rope_pool.astype(latent_pool.dtype)], -1)[:, None]
+    val = latent_pool[:, None]
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     return flash_decode_paged(q, kv, val, block_table, lengths, scale=scale)

@@ -13,8 +13,10 @@ saved rstd). With ``r = rsqrt(mean(x^2)+eps)`` and ``gs = g*scale``:
   dscale = sum_rows(g * x * r)
 
 ``dscale`` needs a cross-tile reduction, so the kernel emits per-tile
-partials of shape (n_tiles, d) and the wrapper sums them — an O(n_tiles*d)
-tensor, not O(rows*d).
+partials of shape (n_tiles, 1, d) and the wrapper sums them — an
+O(n_tiles*d) tensor, not O(rows*d). The singleton middle dim makes each
+partial a (1, d) block equal to its array's last two dims, which Mosaic's
+(8, 128) block tiling accepts where a (1, d) row of (n_tiles, d) is not.
 """
 from __future__ import annotations
 
@@ -43,7 +45,7 @@ def _rmsnorm_bwd_kernel(x_ref, scale_ref, g_ref, dx_ref, dsp_ref, *, eps: float)
     gs = g * s
     dot = jnp.mean(gs * x, axis=-1, keepdims=True)
     dx_ref[...] = (r * gs - x * (r * r * r) * dot).astype(dx_ref.dtype)
-    dsp_ref[...] = jnp.sum(g * x * r, axis=0, keepdims=True)
+    dsp_ref[0] = jnp.sum(g * x * r, axis=0, keepdims=True)
 
 
 def _fwd_call(x2, scale, *, eps: float, br: int, interpret: bool):
@@ -84,15 +86,15 @@ def _rmsnorm2d_bwd(eps, br, interpret, res, g):
         ],
         out_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, d), x2.dtype),
-            jax.ShapeDtypeStruct((n_blocks, d), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, 1, d), jnp.float32),
         ],
         interpret=interpret,
     )(x2, scale, g)
-    return dx, dsp.sum(0).astype(scale.dtype)
+    return dx, dsp.sum((0, 1)).astype(scale.dtype)
 
 
 _rmsnorm2d.defvjp(_rmsnorm2d_fwd, _rmsnorm2d_bwd)

@@ -84,11 +84,9 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
                         moe_cf=choice.moe_cf)
     params_sds = jax.eval_shape(model.init, jax.random.PRNGKey(0))
 
-    # set_mesh (not `with mesh:`) — on new JAX only set_mesh installs the
-    # abstract mesh that with_sharding_constraint/shard_map resolve during
-    # tracing; repro.compat falls back to `with mesh:` on 0.4.x.
-    from repro.compat import set_mesh
-    with set_mesh(mesh):
+    # set_mesh (not `with mesh:`) installs the abstract mesh that
+    # with_sharding_constraint/shard_map resolve during tracing
+    with jax.set_mesh(mesh):
         with axis_rules(rules):
             p_shard = param_shardings(params_sds, mesh, rules)
             if shape.mode == "train":

@@ -1,7 +1,14 @@
 """Production meshes. Importing this module never touches jax device state."""
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: sharding follows the logical-axis
+    rules' constraints (models/sharding.py), not explicit-axis typing."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -36,6 +36,7 @@ from repro.engine.runtime import SwanRuntime
 from repro.engine.rungs import default_rung_ladder
 from repro.engine.session import TrainSession
 from repro.kernels.backend import auto_attn_impl, auto_decode_impl
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import ContinuousBatchingEngine
 from repro.launch.serve import _synthetic_requests
 from repro.launch.train import make_batch_fn
@@ -180,6 +181,7 @@ def main(argv=None):
     ap.add_argument("--quiet", dest="verbose", action="store_false")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     # enable telemetry before any job/engine is constructed so every span
     # source (engine, checkpoint manager, runtime) sees the live instance
     tel = obs.enable() if args.telemetry_out else None

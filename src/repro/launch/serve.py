@@ -11,7 +11,7 @@ Mechanics per decode step:
   - one jitted decode over all slots with a per-slot (B,) cache_len vector;
     the cache is donated (``build_decode_step``) so the per-token update is
     in place, never a full-cache copy;
-  - admission runs single-request prefill and splices the (L, 1, P, ...)
+  - admission runs single-request prefill and splices the (L, 1, ..., P, d)
     prefill cache into the slot with one donated dynamic_update_slice;
   - idle slots decode garbage that is masked out on the host — their
     frozen cache_len keeps the math well-defined and their KV tiles are
@@ -94,6 +94,7 @@ import numpy as np
 from repro import obs
 from repro.configs import get_config
 from repro.kernels.backend import auto_decode_impl
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import (build_decode_step, build_paged_decode_step,
                                 build_paged_prefill_step, build_sampler,
                                 build_paged_spec_decode_step,
@@ -1173,8 +1174,11 @@ def lockstep_generate(model, params, batch, *, prompt_len: int,
             lp = jax.tree_util.tree_map(lambda a, i=i: a[i], params["dec_layers"])
             hd = cfg.head_dim
             B, Senc = enc_h.shape[:2]
-            ks.append((enc_h @ lp["cross_attn"]["wk"]).reshape(B, Senc, cfg.n_kv_heads, hd))
-            vs.append((enc_h @ lp["cross_attn"]["wv"]).reshape(B, Senc, cfg.n_kv_heads, hd))
+            # head-major, the layout decode reads cross KV in
+            ks.append(jnp.swapaxes((enc_h @ lp["cross_attn"]["wk"]).reshape(
+                B, Senc, cfg.n_kv_heads, hd), 1, 2))
+            vs.append(jnp.swapaxes((enc_h @ lp["cross_attn"]["wv"]).reshape(
+                B, Senc, cfg.n_kv_heads, hd), 1, 2))
         cache["cross"] = {"k": jnp.stack(ks), "v": jnp.stack(vs)}
         tokens = jnp.zeros((bsz, 1), jnp.int32)
         pos0 = 0
@@ -1182,9 +1186,9 @@ def lockstep_generate(model, params, batch, *, prompt_len: int,
         logits, pcache = model.prefill(params, {k: jnp.asarray(v) for k, v in batch.items()})
         cache = model.init_cache(bsz, max_len, jnp.float32)
 
-        def splice(buf, pc):
-            if buf.ndim >= 3 and pc.shape[2] == prompt_len and buf.shape[1] == bsz:
-                return buf.at[:, :, :prompt_len].set(pc.astype(buf.dtype))
+        def splice(buf, pc):  # KV caches keep the sequence on axis -2
+            if buf.ndim >= 3 and pc.shape[-2] == prompt_len and buf.shape[1] == bsz:
+                return buf.at[..., :prompt_len, :].set(pc.astype(buf.dtype))
             return pc.astype(buf.dtype) if pc.shape == buf.shape else buf
 
         if cfg.family in ("ssm", "hybrid"):
@@ -1284,6 +1288,7 @@ def main(argv=None):
                          "audit.json)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     tel = obs.enable() if args.telemetry_out else None
     cfg = get_config(args.arch)
     if args.reduced:

@@ -31,6 +31,7 @@ from repro.engine.events import InterferenceTrace, ThermalTrace
 from repro.engine.rungs import Rung, default_rung_ladder
 from repro.engine.session import TrainSession
 from repro.kernels.backend import auto_attn_impl
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.compression import Compressor
 from repro.optim.optimizers import adam, sgd
 
@@ -91,6 +92,7 @@ def main(argv=None):
                          "audit.json)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     tel = obs.enable() if args.telemetry_out else None
     cfg = get_config(args.arch)
     if args.reduced:

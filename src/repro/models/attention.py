@@ -11,6 +11,12 @@ Three implementations of the core softmax-attention compute:
 Decode shards the KV cache sequence dim over the ``kvseq`` logical axis
 (context-parallel decode): softmax over a sharded axis lowers to tiny
 all-reduces of the per-shard max/denominator.
+
+KV caches keep the sequence axis second to last: GQA caches are head-major,
+(B, K, Smax, hd), and paged pools (num_blocks, K, block_size, hd), so a
+decode kernel's KV tile is a (block, hd) slab that Mosaic's (8, 128) block
+tiling accepts. MLA latent caches are (B, Smax, r) and (num_blocks,
+block_size, r). Every scatter and gather below works on axis -2.
 """
 from __future__ import annotations
 
@@ -22,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import apply_norm, apply_rope, dense_init
-from repro.models.sharding import shard  # noqa: F401  (used throughout)
+from repro.models.sharding import mesh_spec, shard
 
 # ---------------------------------------------------------------------------
 # params
@@ -196,7 +202,18 @@ def attention_impl(q, k, v, *, causal, q_offset=0, impl: str = "chunked", chunk:
         return chunked_attention(q, k, v, causal=causal, q_offset=q_offset, chunk=chunk)
     if impl == "pallas":
         from repro.kernels import ops as kops
-        return kops.flash_attention(q, k, v, causal=causal, q_offset=q_offset)
+
+        def kernel(q, k, v):
+            return kops.flash_attention(q, k, v, causal=causal,
+                                        q_offset=q_offset)
+
+        # XLA cannot partition a Mosaic kernel: under a mesh, run it per
+        # shard, split over batch and KV heads (whole GQA groups per shard)
+        spec = mesh_spec(k.shape, "batch", None, "tp", None)
+        if spec is None:
+            return kernel(q, k, v)
+        return jax.shard_map(kernel, in_specs=(spec,) * 3, out_specs=spec,
+                             check_vma=False)(q, k, v)
     raise ValueError(impl)
 
 
@@ -224,22 +241,30 @@ def gqa_forward(p, x, cfg, *, positions=None, kv_x=None, causal=True,
     out = shard(out, "batch", "seq", "tp", None)
     y = out.reshape(B, S, cfg.n_heads * hd) @ p["wo"]
     if return_kv:
-        # cache layout: sequence dim sharded over kvseq (context-parallel
-        # decode) so the prefill scan's ys accumulator shards too
-        return y, {"k": shard(k, "batch", "kvseq", None, None),
-                   "v": shard(v, "batch", "kvseq", None, None)}
+        # head-major cache layout; sequence dim sharded over kvseq
+        # (context-parallel decode) so the prefill scan's ys accumulator
+        # shards too
+        return y, {"k": shard(_heads_first(k), "batch", None, "kvseq", None),
+                   "v": shard(_heads_first(v), "batch", None, "kvseq", None)}
     return y
+
+
+def _heads_first(x):
+    """(B, S, K, hd) <-> (B, K, S, hd): sequence-major activations to the
+    head-major cache layout and back (the naive oracles take sequence-major
+    KV)."""
+    return jnp.swapaxes(x, 1, 2)
 
 
 def init_gqa_cache(cfg, batch: int, max_seq: int, dtype=jnp.bfloat16):
     hd = cfg.head_dim
-    shape = (batch, max_seq, cfg.n_kv_heads, hd)
+    shape = (batch, cfg.n_kv_heads, max_seq, hd)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def init_gqa_pool(cfg, num_blocks: int, block_size: int, dtype=jnp.bfloat16):
     """Paged layout: KV blocks shared by all sequences, no batch dim."""
-    shape = (num_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
+    shape = (num_blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -257,69 +282,79 @@ def _decode_positions(cache_len, B):
     return cache_len.astype(jnp.int32)[:, None]
 
 
+def _on_seq_axis(rows, ndim: int):
+    """(B, Smax) per-position rows, shaped to broadcast against an
+    ``ndim``-dim (B, ..., Smax, d) cache."""
+    return rows.reshape((rows.shape[0],) + (1,) * (ndim - 3)
+                        + (rows.shape[1], 1))
+
+
 def _scatter_token(buf, new, cache_len):
-    """Write ``new`` (B,1,...) into ``buf`` (B,Smax,...) at seq position
-    ``cache_len`` — scalar (lockstep decode, one dynamic slice) or per-
-    sequence (B,) (continuous batching, one-hot masked select)."""
+    """Write ``new`` (B, ..., 1, d) into ``buf`` (B, ..., Smax, d) at seq
+    position ``cache_len`` — scalar (lockstep decode, one dynamic slice) or
+    per-sequence (B,) (continuous batching, one-hot masked select)."""
     if jnp.ndim(cache_len) == 0:
         return jax.lax.dynamic_update_slice_in_dim(
-            buf, new.astype(buf.dtype), cache_len, 1)
-    onehot = jnp.arange(buf.shape[1])[None] == cache_len[:, None]  # (B,Smax)
-    onehot = onehot.reshape(onehot.shape + (1,) * (buf.ndim - 2))
-    return jnp.where(onehot, new.astype(buf.dtype), buf)
+            buf, new.astype(buf.dtype), cache_len, buf.ndim - 2)
+    onehot = jnp.arange(buf.shape[-2])[None] == cache_len[:, None]  # (B,Smax)
+    return jnp.where(_on_seq_axis(onehot, buf.ndim), new.astype(buf.dtype),
+                     buf)
 
 
 def _scatter_token_paged(pool, new, cache_len, block_table):
-    """Write ``new`` (B,1,...) into a block pool (num_blocks, block_size, ...)
-    at virtual position ``cache_len`` of each sequence, routed through its
-    block-table row. Idle serving slots' rows point at the null block, so
-    their masked-garbage writes never touch a live sequence's cache."""
-    bs = pool.shape[1]
+    """Write ``new`` (B, ..., 1, d) into a block pool (num_blocks, ...,
+    block_size, d) at virtual position ``cache_len`` of each sequence,
+    routed through its block-table row. Idle serving slots' rows point at
+    the null block, so their masked-garbage writes never touch a live
+    sequence's cache."""
+    bs = pool.shape[-2]
     B = new.shape[0]
     cl = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (B,))
     blk = jnp.clip(cl // bs, 0, block_table.shape[1] - 1)
     phys = jnp.take_along_axis(jnp.asarray(block_table, jnp.int32),
                                blk[:, None], 1)[:, 0]
     phys = jnp.clip(phys, 0, pool.shape[0] - 1)
-    return pool.at[phys, cl % bs].set(new[:, 0].astype(pool.dtype))
+    # advanced indices split by the head slice put B first: (B, ..., d)
+    return pool.at[phys, ..., cl % bs, :].set(
+        new[..., 0, :].astype(pool.dtype))
 
 
 def _scatter_tokens(buf, new, cache_len):
-    """Write ``new`` (B,S,...) into ``buf`` (B,Smax,...) at seq positions
-    cache_len..cache_len+S-1 (the speculative draft window). Scalar
-    cache_len is one dynamic slice; per-sequence (B,) routes each buffer
-    position p to draft index p - cache_len[b] via a masked gather."""
-    S = new.shape[1]
+    """Write ``new`` (B, ..., S, d) into ``buf`` (B, ..., Smax, d) at seq
+    positions cache_len..cache_len+S-1 (the speculative draft window).
+    Scalar cache_len is one dynamic slice; per-sequence (B,) routes each
+    buffer position p to draft index p - cache_len[b] via a masked gather."""
+    S = new.shape[-2]
     if jnp.ndim(cache_len) == 0:
         return jax.lax.dynamic_update_slice_in_dim(
-            buf, new.astype(buf.dtype), cache_len, 1)
-    rel = jnp.arange(buf.shape[1])[None] - cache_len[:, None]      # (B,Smax)
-    idx = jnp.clip(rel, 0, S - 1).reshape(
-        rel.shape + (1,) * (buf.ndim - 2))
-    sel = jnp.take_along_axis(new.astype(buf.dtype), idx, axis=1)
-    valid = ((rel >= 0) & (rel < S)).reshape(idx.shape)
+            buf, new.astype(buf.dtype), cache_len, buf.ndim - 2)
+    rel = jnp.arange(buf.shape[-2])[None] - cache_len[:, None]     # (B,Smax)
+    idx = _on_seq_axis(jnp.clip(rel, 0, S - 1), buf.ndim)
+    sel = jnp.take_along_axis(new.astype(buf.dtype), idx, axis=buf.ndim - 2)
+    valid = _on_seq_axis((rel >= 0) & (rel < S), buf.ndim)
     return jnp.where(valid, sel, buf)
 
 
 def _scatter_tokens_paged(pool, new, cache_len, block_table):
-    """Write ``new`` (B,S,...) into a block pool at virtual positions
+    """Write ``new`` (B, ..., S, d) into a block pool at virtual positions
     cache_len..cache_len+S-1 of each sequence. The window is at most a few
     tokens, so S single-position scatters (each one indexed pool update)
     beat building a multi-hot routing tensor over the whole pool."""
     cl = jnp.asarray(cache_len, jnp.int32)
-    for i in range(new.shape[1]):
-        pool = _scatter_token_paged(pool, new[:, i:i + 1], cl + i, block_table)
+    for i in range(new.shape[-2]):
+        pool = _scatter_token_paged(pool, new[..., i:i + 1, :], cl + i,
+                                    block_table)
     return pool
 
 
 def _scatter_chunk_paged(pool, new, start, block_table):
-    """Write one block-aligned chunk ``new`` (B, block_size, ...) into a
+    """Write one block-aligned chunk ``new`` (B, ..., block_size, d) into a
     block pool at virtual positions [start, start + block_size), routed
     through each sequence's block-table row. ``start`` may be traced (the
     chunked-prefill loop reuses one compile for every chunk index); it must
     be a multiple of block_size — the chunk grid *is* the block grid, which
     is what lets prefix-cache hits skip whole chunks exactly."""
-    bs = pool.shape[1]
+    bs = pool.shape[-2]
     B = new.shape[0]
     blk_idx = jnp.clip(jnp.asarray(start, jnp.int32) // bs, 0,
                        block_table.shape[1] - 1)
@@ -358,12 +393,14 @@ def gqa_prefill_paged(p, x, cache, start, block_table, cfg, *,
         q = apply_rope(q, pos, cfg.rope_theta)
         k_new = apply_rope(k_new, pos, cfg.rope_theta)
     if write:
-        ck = _scatter_chunk_paged(cache["k"], k_new, start, block_table)
-        cv = _scatter_chunk_paged(cache["v"], v_new, start, block_table)
+        ck = _scatter_chunk_paged(cache["k"], _heads_first(k_new), start,
+                                  block_table)
+        cv = _scatter_chunk_paged(cache["v"], _heads_first(v_new), start,
+                                  block_table)
     else:
         ck, cv = cache["k"], cache["v"]
-    out = naive_attention(q, gather_paged_kv(ck, block_table),
-                          gather_paged_kv(cv, block_table),
+    out = naive_attention(q, _heads_first(gather_paged_kv(ck, block_table)),
+                          _heads_first(gather_paged_kv(cv, block_table)),
                           causal=True, q_offset=start)
     y = out.reshape(B, C, cfg.n_heads * hd) @ p["wo"]
     return y, {"k": ck, "v": cv}
@@ -408,7 +445,7 @@ def mla_prefill_paged(p, x, cache, start, block_table, cfg, *,
 
 
 def gqa_decode(p, x, cache, cache_len, cfg, *, cross_kv=None, impl: str = "naive"):
-    """One-token decode. x: (B,1,d); cache k/v: (B,Smax,K,hd).
+    """One-token decode. x: (B,1,d); cache k/v: (B,K,Smax,hd).
 
     ``cache_len``: scalar (all sequences in lockstep) or (B,) int32 (ragged
     continuous batching). ``impl``: ``naive`` materializes the (H, Smax)
@@ -419,12 +456,13 @@ def gqa_decode(p, x, cache, cache_len, cfg, *, cross_kv=None, impl: str = "naive
     hd = cfg.head_dim
     q = (x @ p["wq"]).reshape(B, 1, cfg.n_heads, hd)
     if cross_kv is not None:
-        k, v = cross_kv
+        k, v = cross_kv  # head-major, as the prefill returns them
         if impl == "pallas":
             from repro.kernels import ops as kops
-            out = kops.decode_attention(q, k, v, k.shape[1])
+            out = kops.decode_attention(q, k, v, k.shape[2])
         else:
-            out = naive_attention(q, k, v, causal=False)
+            out = naive_attention(q, _heads_first(k), _heads_first(v),
+                                  causal=False)
         new_cache = cache
     else:
         k_new = (x @ p["wk"]).reshape(B, 1, cfg.n_kv_heads, hd)
@@ -433,16 +471,17 @@ def gqa_decode(p, x, cache, cache_len, cfg, *, cross_kv=None, impl: str = "naive
             pos = _decode_positions(cache_len, B)
             q = apply_rope(q, pos, cfg.rope_theta)
             k_new = apply_rope(k_new, pos, cfg.rope_theta)
-        ck = _scatter_token(cache["k"], k_new, cache_len)
-        cv = _scatter_token(cache["v"], v_new, cache_len)
-        ck = shard(ck, "batch", "kvseq", None, None)
-        cv = shard(cv, "batch", "kvseq", None, None)
+        ck = _scatter_token(cache["k"], _heads_first(k_new), cache_len)
+        cv = _scatter_token(cache["v"], _heads_first(v_new), cache_len)
+        ck = shard(ck, "batch", None, "kvseq", None)
+        cv = shard(cv, "batch", None, "kvseq", None)
         new_cache = {"k": ck, "v": cv}
         if impl == "pallas":
             from repro.kernels import ops as kops
             out = kops.decode_attention(q, ck, cv, cache_len + 1)
         else:
-            out = naive_attention(q, ck, cv, causal=False, kv_len=cache_len + 1)
+            out = naive_attention(q, _heads_first(ck), _heads_first(cv),
+                                  causal=False, kv_len=cache_len + 1)
     y = out.reshape(B, 1, cfg.n_heads * hd) @ p["wo"]
     return y, new_cache
 
@@ -451,7 +490,7 @@ def gqa_decode_paged(p, x, cache, cache_len, block_table, cfg, *,
                      impl: str = "naive"):
     """One-token GQA decode over a paged KV cache.
 
-    cache: {"k","v"} pools of shape (num_blocks, block_size, K, hd) shared by
+    cache: {"k","v"} pools of shape (num_blocks, K, block_size, hd) shared by
     every sequence; ``block_table`` (B, T) int32 names each sequence's
     blocks. Math is identical to :func:`gqa_decode` on the contiguous cache
     the table describes: scatter the new token's KV at virtual position
@@ -470,15 +509,17 @@ def gqa_decode_paged(p, x, cache, cache_len, block_table, cfg, *,
         pos = _decode_positions(cache_len, B)
         q = apply_rope(q, pos, cfg.rope_theta)
         k_new = apply_rope(k_new, pos, cfg.rope_theta)
-    ck = _scatter_token_paged(cache["k"], k_new, cache_len, block_table)
-    cv = _scatter_token_paged(cache["v"], v_new, cache_len, block_table)
+    ck = _scatter_token_paged(cache["k"], _heads_first(k_new), cache_len,
+                              block_table)
+    cv = _scatter_token_paged(cache["v"], _heads_first(v_new), cache_len,
+                              block_table)
     new_cache = {"k": ck, "v": cv}
     if impl == "pallas":
         from repro.kernels import ops as kops
         out = kops.decode_attention_paged(q, ck, cv, block_table, cache_len + 1)
     else:
-        out = naive_attention(q, gather_paged_kv(ck, block_table),
-                              gather_paged_kv(cv, block_table),
+        out = naive_attention(q, _heads_first(gather_paged_kv(ck, block_table)),
+                              _heads_first(gather_paged_kv(cv, block_table)),
                               causal=False, kv_len=cache_len + 1)
     y = out.reshape(B, 1, cfg.n_heads * hd) @ p["wo"]
     return y, new_cache
@@ -506,16 +547,17 @@ def gqa_decode_spec(p, x, cache, cache_len, cfg, *, impl: str = "naive"):
         pos = _decode_positions(cache_len, B) + jnp.arange(S, dtype=jnp.int32)
         q = apply_rope(q, pos, cfg.rope_theta)
         k_new = apply_rope(k_new, pos, cfg.rope_theta)
-    ck = _scatter_tokens(cache["k"], k_new, cache_len)
-    cv = _scatter_tokens(cache["v"], v_new, cache_len)
-    ck = shard(ck, "batch", "kvseq", None, None)
-    cv = shard(cv, "batch", "kvseq", None, None)
+    ck = _scatter_tokens(cache["k"], _heads_first(k_new), cache_len)
+    cv = _scatter_tokens(cache["v"], _heads_first(v_new), cache_len)
+    ck = shard(ck, "batch", None, "kvseq", None)
+    cv = shard(cv, "batch", None, "kvseq", None)
     new_cache = {"k": ck, "v": cv}
     if impl == "pallas":
         from repro.kernels import ops as kops
         out = kops.decode_attention_spec(q, ck, cv, cache_len)
     else:
-        out = naive_attention(q, ck, cv, causal=True, q_offset=cache_len)
+        out = naive_attention(q, _heads_first(ck), _heads_first(cv),
+                              causal=True, q_offset=cache_len)
     y = out.reshape(B, S, cfg.n_heads * hd) @ p["wo"]
     return y, new_cache
 
@@ -542,16 +584,18 @@ def gqa_decode_spec_paged(p, x, cache, cache_len, block_table, cfg, *,
         pos = _decode_positions(cache_len, B) + jnp.arange(S, dtype=jnp.int32)
         q = apply_rope(q, pos, cfg.rope_theta)
         k_new = apply_rope(k_new, pos, cfg.rope_theta)
-    ck = _scatter_tokens_paged(cache["k"], k_new, cache_len, block_table)
-    cv = _scatter_tokens_paged(cache["v"], v_new, cache_len, block_table)
+    ck = _scatter_tokens_paged(cache["k"], _heads_first(k_new), cache_len,
+                               block_table)
+    cv = _scatter_tokens_paged(cache["v"], _heads_first(v_new), cache_len,
+                               block_table)
     new_cache = {"k": ck, "v": cv}
     if impl == "pallas":
         from repro.kernels import ops as kops
         out = kops.decode_attention_spec_paged(q, ck, cv, block_table,
                                                cache_len)
     else:
-        out = naive_attention(q, gather_paged_kv(ck, block_table),
-                              gather_paged_kv(cv, block_table),
+        out = naive_attention(q, _heads_first(gather_paged_kv(ck, block_table)),
+                              _heads_first(gather_paged_kv(cv, block_table)),
                               causal=True, q_offset=cache_len)
     y = out.reshape(B, S, cfg.n_heads * hd) @ p["wo"]
     return y, new_cache

@@ -122,7 +122,7 @@ def loss_encdec(params, cfg, batch, *, impl="chunked", chunk=1024, remat="none")
 def init_cache_encdec(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
     L = cfg.n_layers
     self_c = init_gqa_cache(cfg, batch, max_len, dtype)
-    cross_shape = (L, batch, cfg.n_audio_frames, cfg.n_kv_heads, cfg.head_dim)
+    cross_shape = (L, batch, cfg.n_kv_heads, cfg.n_audio_frames, cfg.head_dim)
     return {
         "self": jax.tree_util.tree_map(
             lambda a: jnp.broadcast_to(a[None], (L,) + a.shape).copy(), self_c),
@@ -133,7 +133,7 @@ def init_cache_encdec(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
 def decode_step_encdec(params, cfg, cache, tokens, cache_len):
     """One-token decoder step against a prepared cross-KV cache."""
     h = embed_tokens(params["embed"], tokens)
-    pos_table = sinusoidal_positions(cache["self"]["k"].shape[2], cfg.d_model, h.dtype)
+    pos_table = sinusoidal_positions(cache["self"]["k"].shape[-2], cfg.d_model, h.dtype)
     h = h + jax.lax.dynamic_slice_in_dim(pos_table, cache_len, 1, 0)[None]
 
     def body(carry, xs):

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.models.common import activate, dense_init
 from repro.models.sharding import get_rules, resolve
 
@@ -106,8 +105,8 @@ def apply_moe(p, x, cfg, capacity_factor: float = 1.25, mesh=None,
     if isinstance(ep_axes, str):
         ep_axes = (ep_axes,)
     if mesh is None:
-        amesh = compat.get_abstract_mesh()
-        mesh = None if (amesh is None or amesh.empty) else amesh
+        amesh = jax.sharding.get_abstract_mesh()
+        mesh = None if amesh.empty else amesh
     ep_axes = tuple(a for a in (ep_axes or ()) if mesh is not None and a in mesh.axis_names)
 
     def shared_out(x_flat):
@@ -216,7 +215,7 @@ def apply_moe(p, x, cfg, capacity_factor: float = 1.25, mesh=None,
         aux = jax.lax.pmean(aux, manual)
         return y.reshape(Bl, Sl, d).astype(x_loc.dtype), aux
 
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs,
         out_specs=(x_spec, P()), check_vma=False,
         axis_names=set(manual))(*args)
@@ -292,7 +291,7 @@ def _apply_moe_wide_ep(p, x, cfg, mesh, rules, batch_axes, ep_axes, fsdp_axes,
         aux = jax.lax.pmean(aux, manual)
         return y.reshape(Bl, Sl, d).astype(x_loc.dtype), aux
 
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs,
         out_specs=(x_spec, P()), check_vma=False,
         axis_names=set(manual))(*args)

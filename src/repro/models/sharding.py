@@ -23,8 +23,6 @@ from typing import Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import get_abstract_mesh
-
 AxisBinding = Union[None, str, Tuple[str, ...]]
 
 # Default rule set: single-pod (data, model) mesh, FSDP+TP.
@@ -89,9 +87,17 @@ def shard(x: jax.Array, *logical: Optional[str]) -> jax.Array:
 
     No-op outside a mesh context so model code runs unmodified on a bare CPU.
     """
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty:
-        return x
+    spec = mesh_spec(x.shape, *logical)
+    return x if spec is None else jax.lax.with_sharding_constraint(x, spec)
+
+
+def mesh_spec(shape: Tuple[int, ...], *logical: Optional[str]) -> Optional[P]:
+    """The PartitionSpec ``shard`` constrains a ``shape`` array to under
+    the mesh in scope and the current rules; None when no mesh is in scope.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return None
     spec = resolve(*logical)
     # Drop bindings to axes the active mesh doesn't have.
     names = set(mesh.axis_names)
@@ -108,7 +114,7 @@ def shard(x: jax.Array, *logical: Optional[str]) -> jax.Array:
     # Never shard a dim that isn't divisible by its mesh extent.
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     fixed = []
-    for dim, e in zip(x.shape, tuple(spec) + (None,) * (x.ndim - len(spec))):
+    for dim, e in zip(shape, tuple(spec) + (None,) * (len(shape) - len(spec))):
         if e is None:
             fixed.append(None)
             continue
@@ -116,7 +122,7 @@ def shard(x: jax.Array, *logical: Optional[str]) -> jax.Array:
         for a in (e,) if isinstance(e, str) else e:
             extent *= sizes[a]
         fixed.append(e if dim % extent == 0 and dim >= extent else None)
-    return jax.lax.with_sharding_constraint(x, P(*fixed))
+    return P(*fixed)
 
 
 # ---------------------------------------------------------------------------

@@ -440,7 +440,7 @@ def init_cache_decoder(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
     if cfg.family == "hybrid":
         mst = ssm.init_mamba_state(cfg, batch, dtype)
         n_groups = cfg.n_layers // cfg.shared_attn_every
-        kvshape = (n_groups, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+        kvshape = (n_groups, batch, cfg.n_kv_heads, max_len, cfg.head_dim)
         return {
             "mamba": jax.tree_util.tree_map(
                 lambda a: jnp.broadcast_to(a[None], (cfg.n_layers,) + a.shape).copy(), mst),
@@ -448,7 +448,7 @@ def init_cache_decoder(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
         }
     if cfg.family == "vlm":
         n_cross = cfg.n_layers // cfg.cross_attn_every
-        cshape = (n_cross, batch, cfg.n_image_tokens, cfg.n_kv_heads, cfg.head_dim)
+        cshape = (n_cross, batch, cfg.n_kv_heads, cfg.n_image_tokens, cfg.head_dim)
         self_c = init_gqa_cache(cfg, batch, max_len, dtype)
         return {
             "self": jax.tree_util.tree_map(
@@ -463,8 +463,8 @@ def init_cache_decoder(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
 
 def init_paged_cache_decoder(cfg, num_blocks: int, block_size: int,
                              dtype=jnp.bfloat16):
-    """Paged KV layout for dense/moe: per-layer (num_blocks, block_size, ...)
-    pools with a leading layer axis. One block-table row addresses the same
+    """Paged KV layout for dense/moe: per-layer (num_blocks, ..., block_size,
+    d) pools with a leading layer axis. One block-table row addresses the same
     physical block index in every layer's pool, so the table is shared
     across the stack."""
     if cfg.family not in ("dense", "moe"):
@@ -484,7 +484,7 @@ def paged_prefill_step_decoder(params, cfg, cache, tokens, start, block_table,
     tokens: (B, block_size) int32 — one chunk of the (right-padded) prompt;
     ``start`` (traced scalar) is its first virtual position, always a block
     multiple so the chunk occupies exactly one block-table column. KV is
-    written straight into the (L, num_blocks, block_size, ...) pools through
+    written straight into the (L, num_blocks, ..., block_size, d) pools through
     each layer's scatter — there is no contiguous (1, P, ...) prefill cache
     to splice afterwards. ``write=False`` recomputes activations against
     already-populated (prefix-hit) blocks without touching the pools.
@@ -549,7 +549,7 @@ def decode_step_decoder(params, cfg, cache, tokens, cache_len, *, impl="chunked"
     sequence attention, so decode maps them onto {naive, pallas}).
 
     ``block_table`` (B, T) int32 switches the dense/moe KV path to the paged
-    layout: ``cache`` leaves are (L, num_blocks, block_size, ...) pools and
+    layout: ``cache`` leaves are (L, num_blocks, ..., block_size, d) pools and
     every layer resolves the same table row to its own pool.
     """
     B = tokens.shape[0]

@@ -6,7 +6,7 @@ device: slot admission/append/release keep the numpy table in sync with the
 pool's per-sequence tables, and retired slots' rows reset to the null block
 so their masked-garbage decode writes can never land in a live block.
 
-The pool *arrays* themselves (``(num_blocks, block_size, ...)`` per layer)
+The pool *arrays* themselves (``(num_blocks, ..., block_size, d)`` per layer)
 belong to the model (``model.init_paged_cache``) and flow through the jitted
 decode step donated, exactly like the contiguous slabs; this class only
 manages which physical block backs which (slot, logical-block) coordinate.
@@ -40,17 +40,19 @@ from repro.paging.block_pool import BlockEvent, BlockPool
 def gather_paged_kv(pool, block_table):
     """Materialize contiguous caches from a block pool (naive oracle).
 
-    pool: (num_blocks, block_size, ...) — one layer's K, V or latent pool.
+    pool: (num_blocks, ..., block_size, d) — one layer's K, V (head-major,
+    ``...`` = K) or latent (no ``...``) pool.
     block_table: (B, T) int32 physical block ids per logical block.
-    Returns (B, T * block_size, ...): the virtual contiguous cache each
-    sequence sees; positions past its valid length read whatever the mapped
-    (or null) block holds and must be masked by ``kv_len`` downstream.
+    Returns (B, ..., T * block_size, d): the virtual contiguous cache each
+    sequence sees, in the contiguous cache's layout; positions past its
+    valid length read whatever the mapped (or null) block holds and must be
+    masked by ``kv_len`` downstream.
     """
     table = jnp.clip(jnp.asarray(block_table, jnp.int32), 0,
                      pool.shape[0] - 1)
-    gathered = pool[table]  # (B, T, block_size, ...)
-    B, T, bs = gathered.shape[:3]
-    return gathered.reshape((B, T * bs) + gathered.shape[3:])
+    gathered = jnp.moveaxis(pool[table], 1, -3)  # (B, ..., T, block_size, d)
+    T, bs, d = gathered.shape[-3:]
+    return gathered.reshape(gathered.shape[:-3] + (T * bs, d))
 
 
 class PrefixIndex:

@@ -7,6 +7,9 @@ import pytest
 # NOTE: no xla_force_host_platform_device_count here — smoke tests and
 # benches must see 1 device. Multi-device dry-run tests spawn subprocesses.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+# the CLIs the tests drive (and their subprocesses) would otherwise keep a
+# persistent compilation cache in the checkout
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 # Per-test wall-clock timeout via SIGALRM (pytest-timeout is not available
 # in this environment). A hung test — a stuck subprocess wait, a runtime

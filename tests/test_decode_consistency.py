@@ -52,8 +52,11 @@ def test_whisper_decode_matches_forward():
     for i in range(cfg.n_layers):
         lp = jax.tree_util.tree_map(lambda a, i=i: a[i], params["dec_layers"])
         hd = cfg.head_dim
-        ks.append((enc_h @ lp["cross_attn"]["wk"]).reshape(B, -1, cfg.n_kv_heads, hd))
-        vs.append((enc_h @ lp["cross_attn"]["wv"]).reshape(B, -1, cfg.n_kv_heads, hd))
+        # cross KV in the head-major cache layout decode reads
+        ks.append((enc_h @ lp["cross_attn"]["wk"]).reshape(
+            B, -1, cfg.n_kv_heads, hd).swapaxes(1, 2))
+        vs.append((enc_h @ lp["cross_attn"]["wv"]).reshape(
+            B, -1, cfg.n_kv_heads, hd).swapaxes(1, 2))
     cache["cross"] = {"k": jnp.stack(ks), "v": jnp.stack(vs)}
     outs = []
     for t in range(S):
@@ -107,7 +110,9 @@ def test_flash_decode_ragged_vs_ref(cache_dtype):
     k = jax.random.normal(jax.random.fold_in(KEY, 1), (B, Smax, K, hd)).astype(cache_dtype)
     v = jax.random.normal(jax.random.fold_in(KEY, 2), (B, Smax, K, hd)).astype(cache_dtype)
     lengths = jnp.array([7, 40, 0], jnp.int32)  # ragged, incl. an idle slot
-    out = np.asarray(flash_decode(q, k, v, lengths, block_k=16))
+    # the kernel reads the head-major cache; the oracle is sequence-major
+    out = np.asarray(flash_decode(q, k.swapaxes(1, 2), v.swapaxes(1, 2),
+                                  lengths, block_k=16))
     ref = _flash_decode_ref(q, k.astype(jnp.float32), v.astype(jnp.float32),
                             np.asarray(lengths))
     tol = 2e-6 if cache_dtype == jnp.float32 else 2e-2
@@ -119,8 +124,8 @@ def test_flash_decode_bf16_accumulation_toggle():
     from repro.kernels.flash_attention import flash_decode
     B, Smax, K, G, hd = 2, 32, 2, 2, 16
     q = jax.random.normal(KEY, (B, K, G, hd))
-    k = jax.random.normal(jax.random.fold_in(KEY, 1), (B, Smax, K, hd))
-    v = jax.random.normal(jax.random.fold_in(KEY, 2), (B, Smax, K, hd))
+    k = jax.random.normal(jax.random.fold_in(KEY, 1), (B, K, Smax, hd))
+    v = jax.random.normal(jax.random.fold_in(KEY, 2), (B, K, Smax, hd))
     lengths = jnp.array([32, 17], jnp.int32)
     exact = np.asarray(flash_decode(q, k, v, lengths, block_k=16, lowp=False))
     lowp = np.asarray(flash_decode(q, k, v, lengths, block_k=16, lowp=True))
@@ -138,7 +143,7 @@ def test_gqa_decode_pallas_matches_naive(cache_dtype, ragged):
     p = gqa_params(KEY, cfg)
     B, Smax = 3, 24
     x = jax.random.normal(KEY, (B, 1, cfg.d_model)) * 0.1
-    pre = jax.random.normal(jax.random.fold_in(KEY, 3), (B, Smax, cfg.n_kv_heads,
+    pre = jax.random.normal(jax.random.fold_in(KEY, 3), (B, cfg.n_kv_heads, Smax,
                                                          cfg.head_dim)) * 0.3
     cache = {"k": pre.astype(cache_dtype), "v": (pre * 0.7).astype(cache_dtype)}
     cl = jnp.array([5, 23, 1], jnp.int32) if ragged else jnp.int32(6)

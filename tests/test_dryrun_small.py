@@ -31,7 +31,7 @@ from repro.optim.optimizers import sgd
 arch = %r
 cfg = REGISTRY[arch].reduced()
 choice = MeshChoice((2, 2, 2), ("pod", "data", "model"), microbatch=2, remat="dots")
-from repro.compat import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh(choice.mesh_shape, choice.axis_names)
 rules = choice.rules()
 model = build_model(cfg, impl="chunked", chunk=8, remat=choice.remat)
@@ -45,7 +45,7 @@ class Shape:
     global_batch, seq_len, mode = 8, 16, "train"
     name = "tiny"
 
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     with axis_rules(rules):
         p_shard = param_shardings(params_sds, mesh, rules)
         state_shard = {"params": p_shard, "opt": (), "err": (), "step": replicated(mesh)}

@@ -14,6 +14,7 @@ from repro.engine.rungs import Rung, default_rung_ladder, rungs_from_ladder
 from repro.engine.session import TrainSession
 from repro.engine.timeline import Timeline
 from repro.kernels.backend import auto_attn_impl
+from repro.launch.steps import init_train_state
 from repro.launch.train import make_batch_fn
 from repro.optim.optimizers import sgd
 
@@ -142,6 +143,40 @@ def test_rung_jitted_step_is_cached():
     assert f1 is f2
     rung.invalidate()
     assert rung.jitted_step(TINY, opt, lr=0.05) is not f1
+
+
+def test_rung_jitted_step_donates_state():
+    """The step reuses the state's buffers: the passed-in state is consumed."""
+    rung = Rung(name="r", microbatch=1, attn_impl="naive")
+    opt = sgd()
+    state = init_train_state(rung.build_model(TINY), opt, jax.random.PRNGKey(0))
+    new_state, _ = rung.jitted_step(TINY, opt, lr=0.05)(
+        state, make_batch_fn(TINY, 2, 16)(0))
+    leaves = jax.tree_util.tree_leaves(state["params"])
+    assert leaves and all(a.is_deleted() for a in leaves)
+    assert not any(a.is_deleted()
+                   for a in jax.tree_util.tree_leaves(new_state["params"]))
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_enable_compile_cache(env_dir, monkeypatch, tmp_path):
+    """A set JAX_COMPILATION_CACHE_DIR wins; otherwise <checkout>/.jax_cache."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env_dir))
+    try:
+        compile_cache.enable_compile_cache()
+        after = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if env_dir is None:
+        assert after == str(compile_cache.CACHE_DIR)
+        assert (compile_cache.CACHE_DIR.parent / "chip_smoke.py").exists()
+    else:
+        assert after == before
 
 
 # ---------------------------------------------------------------------------

@@ -111,18 +111,19 @@ def test_paged_kv_cache_slot_rows_reset_to_null():
 
 
 def _paged_from_contig(rng, k, v, bs):
-    """Scatter a contiguous (B, Smax, K, hd) cache into a block pool with a
-    random (non-identity) block assignment; returns (k_pool, v_pool, table)."""
-    B, Smax = k.shape[:2]
+    """Scatter a contiguous head-major (B, K, Smax, hd) cache into a block
+    pool (NB, K, bs, hd) with a random (non-identity) block assignment;
+    returns (k_pool, v_pool, table)."""
+    B, K, Smax = k.shape[:3]
     T = Smax // bs
     NB = B * T + 1
     table = rng.permutation(np.arange(1, NB))[:B * T].reshape(B, T).astype(np.int32)
-    k_pool = np.zeros((NB, bs) + k.shape[2:], k.dtype)
-    v_pool = np.zeros((NB, bs) + v.shape[2:], v.dtype)
+    k_pool = np.zeros((NB, K, bs, k.shape[-1]), k.dtype)
+    v_pool = np.zeros((NB, K, bs, v.shape[-1]), v.dtype)
     for b in range(B):
         for t in range(T):
-            k_pool[table[b, t]] = k[b, t * bs:(t + 1) * bs]
-            v_pool[table[b, t]] = v[b, t * bs:(t + 1) * bs]
+            k_pool[table[b, t]] = k[b, :, t * bs:(t + 1) * bs]
+            v_pool[table[b, t]] = v[b, :, t * bs:(t + 1) * bs]
     return k_pool, v_pool, table
 
 
@@ -142,8 +143,8 @@ def test_flash_decode_paged_matches_contiguous(geom, ragged):
         K, G, hd, hdv = 1, 4, 48, 40
         scale = 0.125
     q = rng.standard_normal((B, K, G, hd)).astype(np.float32)
-    k = rng.standard_normal((B, Smax, K, hd)).astype(np.float32)
-    v = rng.standard_normal((B, Smax, K, hdv)).astype(np.float32)
+    k = rng.standard_normal((B, K, Smax, hd)).astype(np.float32)
+    v = rng.standard_normal((B, K, Smax, hdv)).astype(np.float32)
     k_pool, v_pool, table = _paged_from_contig(rng, k, v, bs)
     lengths = np.asarray([Smax, 13, 0], np.int32) if ragged \
         else np.full((B,), 21, np.int32)
@@ -162,7 +163,7 @@ def test_flash_decode_paged_matches_contiguous(geom, ragged):
 
 def test_gather_paged_kv_reconstructs_contiguous():
     rng = np.random.default_rng(1)
-    k = rng.standard_normal((2, 16, 2, 8)).astype(np.float32)
+    k = rng.standard_normal((2, 2, 16, 8)).astype(np.float32)
     k_pool, _, table = _paged_from_contig(rng, k, k, 4)
     back = gather_paged_kv(jnp.asarray(k_pool), jnp.asarray(table))
     np.testing.assert_array_equal(np.asarray(back), k)

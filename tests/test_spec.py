@@ -40,7 +40,7 @@ def _reference(q, k, v, lengths):
         for kh in range(K):
             for qi in range(S):
                 n = int(lengths[b]) + qi + 1
-                kk, vv = k[b, :n, kh], v[b, :n, kh]
+                kk, vv = k[b, kh, :n], v[b, kh, :n]
                 s = (q[b, kh, qi] / np.sqrt(hd)) @ kk.T
                 p = np.exp(s - s.max(-1, keepdims=True))
                 p /= p.sum(-1, keepdims=True)
@@ -52,8 +52,8 @@ def _spec_inputs(seed=0, B=3, K=2, S=3, G=2, hd=64, Smax=160):
     rng = np.random.default_rng(seed)
     q = rng.standard_normal((B, K, S, G, hd)).astype(np.float32)
     k = rng.standard_normal((Smax, B, K, hd)).astype(np.float32)
-    k = np.ascontiguousarray(np.moveaxis(k, 1, 0))  # (B, Smax, K, hd)
-    v = rng.standard_normal((B, Smax, K, hd)).astype(np.float32)
+    k = np.ascontiguousarray(np.moveaxis(k, 0, 2))  # (B, K, Smax, hd)
+    v = rng.standard_normal((B, K, Smax, hd)).astype(np.float32)
     lengths = np.array([5, 63, Smax - S], np.int32)  # edge: last tile full
     return q, k, v, lengths
 
@@ -69,19 +69,19 @@ def test_flash_decode_spec_matches_reference():
 
 def test_flash_decode_spec_paged_matches_reference():
     q, k, v, lengths = _spec_inputs()
-    B, Smax, K, hd = k.shape
+    B, K, Smax, hd = k.shape
     bs = 32
     T = Smax // bs
     # scatter each sequence's blocks into a shuffled physical pool
     rng = np.random.default_rng(1)
     phys = rng.permutation(B * T)
     table = phys.reshape(B, T).astype(np.int32)
-    k_pool = np.zeros((B * T, bs, K, hd), np.float32)
-    v_pool = np.zeros((B * T, bs, K, hd), np.float32)
+    k_pool = np.zeros((B * T, K, bs, hd), np.float32)
+    v_pool = np.zeros((B * T, K, bs, hd), np.float32)
     for b in range(B):
         for t in range(T):
-            k_pool[table[b, t]] = k[b, t * bs:(t + 1) * bs]
-            v_pool[table[b, t]] = v[b, t * bs:(t + 1) * bs]
+            k_pool[table[b, t]] = k[b, :, t * bs:(t + 1) * bs]
+            v_pool[table[b, t]] = v[b, :, t * bs:(t + 1) * bs]
     got = np.asarray(flash_decode_spec_paged(
         jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
         jnp.asarray(table), jnp.asarray(lengths)))
