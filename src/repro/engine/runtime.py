@@ -376,9 +376,10 @@ class SwanRuntime:
                 tick_times: List[float] = []
                 for job in active:
                     report = job.step(tick)
-                    prop = job.observe(
-                        tick, report,
-                        self._slowdown_for(tick, job.sensitivity()))
+                    with tel.span("runtime.observe", job=job.name):
+                        prop = job.observe(
+                            tick, report,
+                            self._slowdown_for(tick, job.sensitivity()))
                     self.work[job.name] += report.work
                     tick_times.append(report.observed_s if report.observed_s
                                       is not None else report.latency_s)
@@ -399,23 +400,24 @@ class SwanRuntime:
                     self.virtual_time_s += max(tick_times)
                 # 5. arbitrated migration (at most one down, one up per tick —
                 # an energy walk-down counts as the tick's downgrade)
-                ctx = self._decision_ctx(active, proposals) \
-                    if tel.enabled else None
-                if tel.enabled:
-                    for j, p in proposals:
-                        rung = self._rung_name(j)
-                        tel.audit.record(tick=tick, job=j.name,
-                                         event="propose", direction=p,
-                                         rule="monitor", from_rung=rung,
-                                         to_rung=rung, **ctx)
-                self._arbitrate(tick, active, proposals,
-                                allow_upgrades=not energy_pressed,
-                                allow_downgrades=not energy_walked,
-                                ctx=ctx)
-                for job in active:
-                    job.end_tick(tick)
-                self._publish_metrics(tick, active)
-                tel.snap(tick)
+                with tel.span("runtime.arbitrate", tick=tick):
+                    ctx = self._decision_ctx(active, proposals) \
+                        if tel.enabled else None
+                    if tel.enabled:
+                        for j, p in proposals:
+                            rung = self._rung_name(j)
+                            tel.audit.record(tick=tick, job=j.name,
+                                             event="propose", direction=p,
+                                             rule="monitor", from_rung=rung,
+                                             to_rung=rung, **ctx)
+                    self._arbitrate(tick, active, proposals,
+                                    allow_upgrades=not energy_pressed,
+                                    allow_downgrades=not energy_walked,
+                                    ctx=ctx)
+                    for job in active:
+                        job.end_tick(tick)
+                    self._publish_metrics(tick, active)
+                    tel.snap(tick)
                 self.ticks += 1
         # a burst running past the horizon must not strand paused jobs:
         # whoever the runtime paused is resumed before the loop closes

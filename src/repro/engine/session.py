@@ -350,15 +350,19 @@ class TrainSession(SocJob):
         step = self._step_idx
         rung = self.rung
         self._ran_tick = tick
-        batch = self.batch_fn(step)
+        tel = obs.get_telemetry()
+        with tel.span("train.batch", job=self.name, step=step):
+            batch = self.batch_fn(step)
         warmup = self._steps_on_rung == 0
         # compile=True marks the first quantum on a rung (pays trace+compile)
         # so the trace distinguishes compile spans from steady-state steps
-        with obs.get_telemetry().span("train.step", job=self.name, step=step,
-                                      rung=rung.name, compile=warmup):
+        with tel.span("train.step", job=self.name, step=step,
+                      rung=rung.name, compile=warmup):
             t0 = time.perf_counter()
-            self._state, metrics = self._run_step(self._state, batch)
-            loss = float(metrics["loss"])  # blocks until the step is done
+            with tel.span("train.dispatch"):
+                self._state, metrics = self._run_step(self._state, batch)
+            with tel.span("train.wait"):
+                loss = float(metrics["loss"])  # blocks until the step is done
             dt = time.perf_counter() - t0
         self._steps_on_rung += 1
         self._losses.append(loss)

@@ -67,4 +67,5 @@ def depthwise_conv(x, w, *, channel_block: int = 128,
         out_specs=pl.BlockSpec((1, H, W, cb), lambda b, c: (b, 0, 0, c)),
         out_shape=jax.ShapeDtypeStruct((B, H, W, C), x.dtype),
         interpret=interpret,
+        name="depthwise_conv",
     )(xp, w)

@@ -194,6 +194,7 @@ def _fwd_call(q, k, v, *, causal: bool, q_offset: int, bq: int, bk: int,
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
         **_grid_params(interpret),
     )(q, k, v)
 
@@ -329,6 +330,7 @@ def _bwd_call(q, k, v, o, lse, do, *, causal: bool, q_offset: int, bq: int,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
         **_grid_params(interpret),
     )(q, k, v, do, lse, delta)
 
@@ -361,6 +363,7 @@ def _bwd_call(q, k, v, o, lse, do, *, causal: bool, q_offset: int, bq: int,
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
                         pltpu.VMEM((bk, hd), jnp.float32)],
         interpret=interpret,
+        name="flash_dkv",
         **_grid_params(interpret),
     )(k, v, q, do, lse, delta)
     return dq, dk, dv
@@ -544,6 +547,7 @@ def flash_decode(q, k, v, lengths, *, scale: Optional[float] = None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, hdv), q.dtype),
         interpret=interp,
+        name="flash_decode",
         **_decode_grid_params(interp),
     )(lengths, q, k, v)
 
@@ -625,6 +629,7 @@ def flash_decode_paged(q, k_pool, v_pool, block_table, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, hdv), q.dtype),
         interpret=interp,
+        name="flash_decode_paged",
         **_decode_grid_params(interp),
     )(lengths, table, q, k_pool, v_pool)
 
@@ -744,6 +749,7 @@ def flash_decode_spec(q, k, v, lengths, *, scale: Optional[float] = None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, S * G, hdv), q.dtype),
         interpret=interp,
+        name="flash_decode_spec",
         **_decode_grid_params(interp),
     )(lengths, q.reshape(B, K, S * G, hd), k, v)
     return out.reshape(B, K, S, G, hdv)
@@ -810,6 +816,7 @@ def flash_decode_spec_paged(q, k_pool, v_pool, block_table, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, S * G, hdv), q.dtype),
         interpret=interp,
+        name="flash_decode_spec_paged",
         **_decode_grid_params(interp),
     )(lengths, table, q.reshape(B, K, S * G, hd), k_pool, v_pool)
     return out.reshape(B, K, S, G, hdv)

@@ -60,6 +60,7 @@ def _fwd_call(x2, scale, *, eps: float, br: int, interpret: bool):
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x2.dtype),
         interpret=interpret,
+        name="rmsnorm_fwd",
     )(x2, scale)
 
 
@@ -93,6 +94,7 @@ def _rmsnorm2d_bwd(eps, br, interpret, res, g):
             jax.ShapeDtypeStruct((n_blocks, 1, d), jnp.float32),
         ],
         interpret=interpret,
+        name="rmsnorm_bwd",
     )(x2, scale, g)
     return dx, dsp.sum((0, 1)).astype(scale.dtype)
 

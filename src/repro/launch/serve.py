@@ -119,6 +119,7 @@ class Request:
     # "timeout" rejection instead of waiting forever (None = patient)
     deadline_steps: Optional[int] = None
     submitted_at: int = -1  # engine decode_steps at submit(); set by submit
+    submitted_s: float = -1.0  # time.perf_counter() at submit(); set by submit
 
 
 @dataclasses.dataclass
@@ -230,6 +231,8 @@ class ContinuousBatchingEngine:
         self._hold_seq = 0
         self.decode_steps = 0
         self.tokens_out = 0
+        self.admitted = 0        # requests admitted to a slot
+        self.queue_wait_s = 0.0  # host seconds from submit to admission, summed
         self._active_slot_steps = 0
         self._uid_prompt_len: Dict[int, int] = {}
         self.prefill_lengths: Dict[int, int] = {}  # padded length -> count
@@ -393,6 +396,7 @@ class ContinuousBatchingEngine:
             self._reject(req, "shed")
             return False
         req.submitted_at = self.decode_steps
+        req.submitted_s = time.perf_counter()
         self.queue.append(req)
         return True
 
@@ -633,6 +637,9 @@ class ContinuousBatchingEngine:
         self._resident_since[slot] = self.decode_steps
         self.admission_waits[req.uid] = max(
             0, self.decode_steps - max(req.submitted_at, 0))
+        self.admitted += 1
+        if req.submitted_s >= 0:
+            self.queue_wait_s += time.perf_counter() - req.submitted_s
         self.tokens_out += 1
         if self.draft is not None:
             self.draft.admit(slot, [int(t) for t in req.prompt])
@@ -913,7 +920,9 @@ class ContinuousBatchingEngine:
         self._expire_deadlines()
         if self.swapped:
             self._try_swap_in()
-        self._admit_waiting()
+        tel = obs.get_telemetry()
+        with tel.span("serve.admit"):
+            self._admit_waiting()
         active = [s for s in range(self.max_batch) if self.slot_uid[s] is not None]
         if not active:
             # nothing resident but work still pending (queued behind held
@@ -931,46 +940,50 @@ class ContinuousBatchingEngine:
         k = self._spec_k(active)
         if k >= 1:
             return self._step_speculative(active, k)
+        tables = ()
         if self.kv is not None:
             self._append_positions(active, 1)
             self._ship_dirty_tables()
-            with obs.get_telemetry().span("serve.decode",
-                                          batch=len(active)):
-                next_tok, logits, self.cache = self._decode(
-                    self.params, self.cache, jnp.asarray(self.tokens),
-                    jnp.asarray(self.cache_len), self._dev_tables)
-        else:
-            with obs.get_telemetry().span("serve.decode",
-                                          batch=len(active)):
-                next_tok, logits, self.cache = self._decode(
-                    self.params, self.cache, jnp.asarray(self.tokens),
-                    jnp.asarray(self.cache_len))
-        if self._sampler is None:
-            next_np = np.asarray(next_tok)
-        else:
-            uids = np.asarray([self.slot_uid[s] if self.slot_uid[s] is not None
-                               else 0 for s in range(self.max_batch)], np.int32)
-            idxs = np.asarray([len(self.generated[s])
-                               for s in range(self.max_batch)], np.int32)
-            keys = self._keys(jnp.asarray(uids), jnp.asarray(idxs))
-            next_np = np.asarray(self._sampler(logits[:, -1], keys))[:, None]
+            tables = (self._dev_tables,)
+        # one decode step, from its dispatch to the host's read of the next
+        # tokens; serve.decode_wait is the time the host blocks on the device
+        with tel.span("serve.decode", batch=len(active)):
+            next_tok, logits, self.cache = self._decode(
+                self.params, self.cache, jnp.asarray(self.tokens),
+                jnp.asarray(self.cache_len), *tables)
+            if self._sampler is None:
+                with tel.span("serve.decode_wait"):
+                    next_np = np.asarray(next_tok)
+            else:
+                uids = np.asarray([self.slot_uid[s]
+                                   if self.slot_uid[s] is not None else 0
+                                   for s in range(self.max_batch)], np.int32)
+                idxs = np.asarray([len(self.generated[s])
+                                   for s in range(self.max_batch)], np.int32)
+                keys = self._keys(jnp.asarray(uids), jnp.asarray(idxs))
+                with tel.span("serve.decode_wait"):
+                    next_np = np.asarray(
+                        self._sampler(logits[:, -1], keys))[:, None]
         self.decode_steps += 1
         self._active_slot_steps += len(active)
         emitted = []
-        for slot in active:
-            tok = int(next_np[slot, 0])
-            self.generated[slot].append(tok)
-            self.cache_len[slot] += 1
-            self.tokens[slot, 0] = tok
-            self.tokens_out += 1
-            emitted.append((self.slot_uid[slot], tok))
-            if self.draft is not None:
-                # keep the draft's view of the stream current even while
-                # speculation is off (depth walked to 0, or a clamped round)
-                self.draft.commit(slot, [], tok)
-            if self._should_retire(slot, tok):
-                self._retire(slot, "eos" if (self.eos_id is not None and
-                                             tok == self.eos_id) else "length")
+        with tel.span("serve.emit", batch=len(active)):
+            for slot in active:
+                tok = int(next_np[slot, 0])
+                self.generated[slot].append(tok)
+                self.cache_len[slot] += 1
+                self.tokens[slot, 0] = tok
+                self.tokens_out += 1
+                emitted.append((self.slot_uid[slot], tok))
+                if self.draft is not None:
+                    # keep the draft's view of the stream current even while
+                    # speculation is off (depth walked to 0, or a clamped
+                    # round)
+                    self.draft.commit(slot, [], tok)
+                if self._should_retire(slot, tok):
+                    self._retire(slot, "eos" if (self.eos_id is not None and
+                                                 tok == self.eos_id)
+                                 else "length")
         return emitted
 
     def _step_speculative(self, active: List[int], k: int) -> List[Tuple[int, int]]:
@@ -1000,18 +1013,15 @@ class ContinuousBatchingEngine:
             win[slot, 1:] = drafts[row]
             if probs_b is not None:
                 probs_b[slot] = dprobs[row]
+        tables = ()
         if self.kv is not None:
             self._append_positions(active, S)
             self._ship_dirty_tables()
-            with tel.span("serve.spec_verify", batch=len(active), k=k):
-                logits, self.cache = self._spec_decode(
-                    self.params, self.cache, jnp.asarray(win),
-                    jnp.asarray(self.cache_len), self._dev_tables)
-        else:
-            with tel.span("serve.spec_verify", batch=len(active), k=k):
-                logits, self.cache = self._spec_decode(
-                    self.params, self.cache, jnp.asarray(win),
-                    jnp.asarray(self.cache_len))
+            tables = (self._dev_tables,)
+        with tel.span("serve.spec_verify", batch=len(active), k=k):
+            logits, self.cache = self._spec_decode(
+                self.params, self.cache, jnp.asarray(win),
+                jnp.asarray(self.cache_len), *tables)
         if self._sampler is None:
             toks, n_emit = self._greedy_verify(logits,
                                                jnp.asarray(win[:, 1:]))
@@ -1113,6 +1123,8 @@ class ContinuousBatchingEngine:
         out = {
             "decode_steps": self.decode_steps,
             "tokens_out": self.tokens_out,
+            "admitted": self.admitted,
+            "queue_wait_s": self.queue_wait_s,
             "occupancy": round(self.occupancy, 4),
             "kv_layout": self.kv_layout,
             "prefill_buckets": {str(k): v for k, v in

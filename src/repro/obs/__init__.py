@@ -1,16 +1,18 @@
 """repro.obs — one telemetry plane for the whole reproduction.
 
-Bundles the three instruments from ISSUE 9 behind a single switch:
+Bundles three instruments behind a single switch:
 
 * :class:`~repro.obs.metrics.MetricsRegistry` — labeled counters /
   gauges / histograms with shared no-op handles when disabled;
 * :class:`~repro.obs.trace.SpanTracer` — thread-safe contextmanager
-  spans on monotonic clocks, exported as Chrome-trace JSON;
+  spans on monotonic clocks, exported as Chrome-trace JSON, each also a
+  ``jax.profiler.TraceAnnotation`` so a captured profile shows them;
 * :class:`~repro.obs.audit.AuditLog` — the arbiter decision audit.
 
-The process-global instance starts **disabled** (every hot-path call is
-an enabled-check + shared no-op object), so importing this module from
-kernels/engines costs nothing. CLIs flip it on via :func:`enable` when
+The process-global instance starts **disabled**: metrics are an
+enabled-check + shared no-op object, and a span is only its profiler
+annotation, which records while a profile is being captured and costs its
+inactive check otherwise. CLIs flip it on via :func:`enable` when
 ``--telemetry-out`` is passed; tests swap it with :func:`set_telemetry`.
 Components always fetch it lazily (``obs.get_telemetry()``) so enabling
 works regardless of construction order.

@@ -7,6 +7,12 @@ swap in/out, fleet invite→accept — and exports Chrome-trace JSON
 (``{"traceEvents": [...]}``, "X" complete events, µs timestamps) that
 loads directly in Perfetto / ``chrome://tracing``.
 
+Every span is also a ``jax.profiler.TraceAnnotation`` of the span's name,
+so a captured profile holds the program's phases on its host plane, on the
+same clock as the device's operations. With telemetry disabled the
+profiler alone decides: the annotation records only while a profile is
+being captured, and nothing lands in the in-memory record.
+
 Nesting is tracked per thread (``threading.local`` stacks); completed
 spans land in one lock-protected list with a bounded cap so multi-day
 fleet runs cannot exhaust memory (drops are counted, never silent).
@@ -19,6 +25,8 @@ import sys
 import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.schema import encode_record, versioned
 
@@ -33,28 +41,17 @@ class SpanRecord:
     args: Dict = dataclasses.field(default_factory=dict)
 
 
-class _NoopSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP_SPAN = _NoopSpan()
-
-
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_start", "_depth")
+    __slots__ = ("_tracer", "name", "args", "_start", "_depth", "_annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: Dict):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._annotation = TraceAnnotation(name)
 
     def __enter__(self):
+        self._annotation.__enter__()
         stack = self._tracer._stack()
         self._depth = len(stack)
         stack.append(self)
@@ -71,6 +68,7 @@ class _Span:
         if exc_type is not None:
             self.args = dict(self.args, error=exc_type.__name__)
         self._tracer._record(self, self._start, end)
+        self._annotation.__exit__(exc_type, exc, tb)
         return False  # never swallow exceptions
 
 
@@ -95,9 +93,12 @@ class SpanTracer:
         return stack
 
     def span(self, name: str, **args):
-        """``with tracer.span("serve.decode", batch=4): ...``"""
+        """``with tracer.span("serve.decode", batch=4): ...``
+
+        The profiler annotation carries ``name`` alone; ``args`` go to the
+        in-memory record only."""
         if not self.enabled:
-            return _NOOP_SPAN
+            return TraceAnnotation(name)
         return _Span(self, name, args)
 
     def _record(self, span: _Span, start: float, end: float) -> None:

@@ -3,9 +3,11 @@ audit, schema-versioned serialization, and the end-to-end telemetry bundle
 from a mixed-workload run.
 
 The contract under test: telemetry off is free (shared no-op handles, no
-records), telemetry on is complete (every serve/train/pool/energy/thermal
-stat in one schema, every phase a span, every migration an audit record
-carrying the scores that decided it)."""
+records; spans are only profiler annotations), telemetry on is complete
+(every serve/train/pool/energy/thermal stat in one schema, every phase a
+span, every migration an audit record carrying the scores that decided
+it)."""
+import glob
 import io
 import json
 
@@ -16,7 +18,7 @@ from repro import obs
 from repro.engine.timeline import Timeline
 from repro.obs.metrics import NOOP, MetricsRegistry
 from repro.obs.schema import SCHEMA_VERSION, encode_record, versioned
-from repro.obs.trace import _NOOP_SPAN, SpanTracer
+from repro.obs.trace import SpanTracer
 
 
 @pytest.fixture(autouse=True)
@@ -140,13 +142,13 @@ def test_tracer_nesting_and_chrome_trace_ordering():
 
 
 def test_tracer_disabled_returns_shared_noop_span():
+    # a disabled span is only its profiler annotation: it records nothing
+    # in the tracer, nested or not
     tr = SpanTracer(enabled=False)
-    s1 = tr.span("a", k=1)
-    s2 = tr.span("b")
-    assert s1 is _NOOP_SPAN and s2 is _NOOP_SPAN
-    with s1:
-        pass
-    assert tr.spans() == []
+    with tr.span("a", k=1):
+        with tr.span("b"):
+            assert tr.active_stack() == {}
+    assert tr.spans() == [] and tr.by_name() == {}
 
 
 def test_tracer_records_exception_and_reraises():
@@ -266,13 +268,97 @@ def test_telemetry_bundle_save_and_debug_dump(tmp_path):
 def test_disabled_telemetry_dump_and_noop_identity():
     tel = obs.get_telemetry()
     assert not tel.enabled
-    assert tel.span("x") is _NOOP_SPAN
+    with tel.span("x", k=1):  # records nothing in the bundle
+        pass
+    assert tel.tracer.spans() == []
     assert tel.metrics.counter("c") is NOOP
     buf = io.StringIO()
     tel.debug_dump(file=buf)
     assert "telemetry disabled" in buf.getvalue()
     tel.snap(0)
     assert tel.snapshots == []
+
+
+# ---------------------------------------------------------------------------
+# spans as profiler annotations (telemetry disabled)
+# ---------------------------------------------------------------------------
+
+TRAIN_PHASES = ("runtime.tick", "train.batch", "train.step", "train.dispatch",
+                "train.wait", "runtime.observe", "runtime.arbitrate")
+
+
+def _tiny_train_runtime(ticks):
+    from repro.configs.base import ModelConfig
+    from repro.engine.rungs import Rung
+    from repro.engine.runtime import SwanRuntime
+    from repro.engine.session import TrainSession
+    from repro.launch.train import make_batch_fn
+    from repro.optim.optimizers import sgd
+    cfg = ModelConfig(name="tiny", family="dense", n_layers=2, d_model=64,
+                      n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
+                      tie_embeddings=True, source="test")
+    session = TrainSession(cfg, [Rung(name="r", attn_impl="naive")],
+                           optimizer=sgd(), batch_fn=make_batch_fn(cfg, 2, 16),
+                           adaptive=False, verbose=False)
+    session.bind(ticks)
+    return SwanRuntime([session])
+
+
+def _profile_host_events(logdir):
+    """(start_ns, end_ns, name) of every event on the host planes of the
+    one profile captured under ``logdir``."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(str(logdir / "**" / "*.xplane.pb"), recursive=True)
+    pd = ProfileData.from_file(path)
+    return [(ev.start_ns, ev.end_ns, ev.name) for plane in pd.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_train_phase_spans_reach_the_profile_with_telemetry_disabled(tmp_path):
+    import jax
+    rt = _tiny_train_runtime(3)
+    rt.run(1)  # compile outside the capture
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        rt.run(3, start=1)
+    finally:
+        jax.profiler.stop_trace()
+    events = _profile_host_events(tmp_path)
+    by = {n: sorted(e for e in events if e[2] == n) for n in TRAIN_PHASES}
+    assert {n: len(v) for n, v in by.items()} == {n: 2 for n in TRAIN_PHASES}
+
+    def within(inner, outer):
+        return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+    def before(a, b):
+        return a[1] <= b[0]
+
+    for i in range(2):
+        ph = {n: by[n][i] for n in TRAIN_PHASES}
+        assert all(within(ph[n], ph["runtime.tick"]) for n in TRAIN_PHASES)
+        assert within(ph["train.dispatch"], ph["train.step"])
+        assert within(ph["train.wait"], ph["train.step"])
+        assert before(ph["train.dispatch"], ph["train.wait"])
+        assert before(ph["train.batch"], ph["train.step"])
+        assert before(ph["train.step"], ph["runtime.observe"])
+        assert before(ph["runtime.observe"], ph["runtime.arbitrate"])
+    assert before(by["runtime.tick"][0], by["runtime.tick"][1])
+    assert obs.get_telemetry().tracer.spans() == []
+
+
+def test_disabled_spans_leave_the_bundle_empty_without_a_capture(tmp_path):
+    rt = _tiny_train_runtime(2)
+    rt.run(2)
+    tel = obs.get_telemetry()
+    assert tel.tracer.spans() == []
+    paths = tel.save(str(tmp_path / "tel"))
+    span_lines = [json.loads(l) for l in open(paths["spans"])]
+    assert span_lines == [versioned({"stream": "spans"})]
+    trace = json.load(open(paths["trace"]))
+    assert [e for e in trace["traceEvents"] if e["ph"] == "X"] == []
 
 
 # ---------------------------------------------------------------------------
@@ -306,6 +392,32 @@ def test_cow_and_prefill_spans_recorded():
     assert agg["serve.cow_copy"]["count"] == engine.stats()["cow_copies"]
     assert agg["serve.prefill_chunk"]["count"] > 0
     assert agg["serve.decode"]["count"] == engine.decode_steps
+
+
+def test_decode_span_holds_its_wait_and_queue_counters():
+    from repro.launch.serve import Request
+    tel = obs.set_telemetry(obs.Telemetry(enabled=True)) and obs.get_telemetry()
+    engine = _tiny_engine(max_batch=2, max_seq=32, kv_layout="paged",
+                          block_size=4)
+    n = 3
+    done = engine.run([Request(uid=i, prompt=np.arange(2, 9, dtype=np.int32),
+                               max_new_tokens=4) for i in range(n)])
+    spans = tel.tracer.spans()
+    decodes = [s for s in spans if s.name == "serve.decode"]
+    waits = [s for s in spans if s.name == "serve.decode_wait"]
+    assert len(decodes) == engine.decode_steps > 0
+    assert len(waits) == len(decodes)
+    for d, w in zip(decodes, waits):
+        assert w.depth == d.depth + 1
+        assert d.ts_us <= w.ts_us
+        assert w.ts_us + w.dur_us <= d.ts_us + d.dur_us
+    agg = tel.tracer.by_name()
+    assert agg["serve.admit"]["count"] == agg["serve.emit"]["count"] == \
+        engine.decode_steps
+    assert engine.admitted == len(done) == n
+    assert engine.queue_wait_s >= 0.0
+    st = engine.stats()
+    assert st["admitted"] == n and st["queue_wait_s"] == engine.queue_wait_s
 
 
 def test_swap_spans_recorded():

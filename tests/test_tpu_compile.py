@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +62,36 @@ def test_flash_attention_fwd_bwd_compiles(one_chip):
 
     hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
     assert hlo.count("tpu_custom_call") == 3  # fwd, dq, dkv
+
+
+def test_kernel_names_are_hlo_instruction_names(one_chip, monkeypatch):
+    """A kernel's ``name`` becomes its instruction's name in the compiled
+    program, which is the op name a device profile keys its time by: the
+    train step at granite-3-2b widths (two layers) holds the three flash
+    kernels, the paged decode program its kernel."""
+    from repro.configs import get_config
+    from repro.engine.rungs import Rung
+    from repro.launch.steps import init_train_state
+    from repro.optim.optimizers import adam
+
+    monkeypatch.setattr("repro.kernels.backend.default_interpret",
+                        lambda: False)
+    cfg = dataclasses.replace(get_config("granite-3-2b"), n_layers=2)
+    rung, opt = Rung(name="r", attn_impl="pallas"), adam()
+    state = jax.eval_shape(lambda: init_train_state(
+        rung.build_model(cfg), opt, jax.random.PRNGKey(0)))
+    state = jax.tree_util.tree_map(
+        lambda a: _sds(one_chip, a.shape, a.dtype), state)
+    batch = {"tokens": _sds(one_chip, (1, 512), jnp.int32)}
+    train = rung.jitted_step(cfg, opt).lower(state, batch).compile().as_text()
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert re.search(rf"%{name}\.\d+ = .*tpu_custom_call", train), name
+    s = lambda *a: _sds(one_chip, *a)  # noqa: E731
+    pool = s((NB, K, BS, HD))
+    decode = _compile(lambda q, k, v, t, n: fa.flash_decode_paged(
+        q, k, v, t, n, interpret=False), s((B, K, H // K, HD)), pool, pool,
+        s((B, SMAX // BS), jnp.int32), s((B,), jnp.int32))
+    assert re.search(r"%flash_decode_paged\.\d+ = .*tpu_custom_call", decode)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
