@@ -2,8 +2,10 @@
 
 ``jax.grad`` through ``attention_impl(..., impl="pallas")`` must match the
 naive oracle — across causal/non-causal, GQA (K < H, including MQA), and
-sequence lengths that are not multiples of the 128 default block (which force
-the ragged-divisor block path). rmsnorm grads check against kernels/ref.py.
+sequence lengths that do not divide into the planned blocks (which force the
+ragged-divisor block path), several heads a grid step, causal triangles of
+several blocks whose diagonal tiles straddle, and queries offset into a
+longer key sequence. rmsnorm grads check against kernels/ref.py.
 """
 import jax
 import jax.numpy as jnp
@@ -26,26 +28,37 @@ def _qkv(B, Sq, Sk, H, K, hd):
     return q, k, v
 
 
-def _grads(impl, q, k, v, causal, w):
-    def loss(q, k, v):
-        out = attention_impl(q, k, v, causal=causal, impl=impl)
-        return (out.astype(jnp.float32) * w).sum()
-    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+def _grads(impl, q, k, v, causal, w, q_offset=0):
+    """The output and the gradient of ``sum(out * w)``."""
+    out, vjp = jax.vjp(lambda q, k, v: attention_impl(
+        q, k, v, causal=causal, q_offset=q_offset, impl=impl), q, k, v)
+    return out, vjp(w.astype(out.dtype))
 
 
-@pytest.mark.parametrize("B,Sq,Sk,H,K,hd", [
-    (1, 16, 16, 4, 4, 32),     # MHA
-    (2, 37, 37, 8, 4, 16),     # GQA, ragged (block != divisor of 128)
-    (1, 128, 128, 8, 2, 64),   # GQA at exactly one default block
-    (1, 256, 256, 4, 1, 32),   # MQA, multi-block (causal tile skipping live)
-    (1, 48, 112, 4, 2, 32),    # Sq != Sk (cross-attention shape)
+def _case(*shape, q_offset=0):
+    return pytest.param(*shape, q_offset, id="-".join(map(str, shape)) + (
+        f"-offset{q_offset}" if q_offset else ""))
+
+
+@pytest.mark.parametrize("B,Sq,Sk,H,K,hd,q_offset", [
+    _case(1, 16, 16, 4, 4, 32),     # MHA
+    _case(2, 37, 37, 8, 4, 16),     # GQA, ragged (block != divisor of 128)
+    _case(1, 128, 128, 8, 2, 64),   # GQA at exactly one default block
+    _case(1, 256, 256, 4, 1, 32),   # MQA, multi-block (causal tile skipping live)
+    _case(1, 48, 112, 4, 2, 32),    # Sq != Sk (cross-attention shape)
+    _case(1, 1100, 1100, 4, 2, 64),  # ragged blocks, 2 heads a step, straddling
+    _case(1, 1536, 1536, 2, 1, 128),  # hd 128, a causal triangle of blocks
+    _case(2, 96, 160, 4, 4, 64, q_offset=64),  # queries end the key sequence
+    _case(1, 640, 1152, 4, 2, 32, q_offset=512),  # offset across blocks
 ])
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_grad_parity(B, Sq, Sk, H, K, hd, causal):
+def test_flash_attention_grad_parity(B, Sq, Sk, H, K, hd, q_offset, causal):
     q, k, v = _qkv(B, Sq, Sk, H, K, hd)
     w = jax.random.normal(jax.random.fold_in(KEY, 3), (B, Sq, H, hd))
-    gp = _grads("pallas", q, k, v, causal, w)
-    gn = _grads("naive", q, k, v, causal, w)
+    op, gp = _grads("pallas", q, k, v, causal, w, q_offset)
+    on, gn = _grads("naive", q, k, v, causal, w, q_offset)
+    np.testing.assert_allclose(np.asarray(op), np.asarray(on), **TOL,
+                               err_msg="output mismatch")
     for name, a, b in zip("qkv", gp, gn):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), **TOL,
                                    err_msg=f"d{name} mismatch")

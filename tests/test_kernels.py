@@ -37,7 +37,8 @@ def test_depthwise_sweep(shape, k, dtype):
 
 
 @pytest.mark.parametrize("B,S,H,K,hd", [(1, 16, 4, 4, 32), (2, 37, 8, 4, 16),
-                                        (1, 128, 8, 2, 64), (2, 64, 4, 1, 32)])
+                                        (1, 128, 8, 2, 64), (2, 64, 4, 1, 32),
+                                        (1, 1100, 4, 2, 64), (1, 1536, 2, 1, 128)])
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_attention_sweep(B, S, H, K, hd, causal):
     q = jax.random.normal(KEY, (B, S, H, hd))
@@ -47,6 +48,55 @@ def test_flash_attention_sweep(B, S, H, K, hd, causal):
     got = ops.flash_attention(q, k, v, causal=causal)
     want = naive_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+def _causal_tiles(plan):
+    """Tiles of the (Sq, Sk) score matrix that hold a visible entry."""
+    q_pos = np.arange(plan.Sq) + plan.q_offset
+    seen = np.arange(plan.Sk)[None, :] <= q_pos[:, None]
+    return int(seen.reshape(plan.Sq // plan.bq, plan.bq, plan.Sk // plan.bk,
+                            plan.bk).any(axis=(1, 3)).sum())
+
+
+@pytest.mark.parametrize("B,H,Sq,Sk,hd,q_offset", [
+    (2, 32, 2048, 2048, 64, 0),     # granite-3-2b's training step
+    (2, 32, 2048, 2048, 128, 0),    # head_dim 128 (nemotron-4-15b)
+    (1, 4, 1100, 1100, 64, 0),      # ragged blocks
+    (2, 4, 640, 1152, 32, 512),     # queries offset into longer keys
+])
+def test_train_plan(B, H, Sq, Sk, hd, q_offset):
+    """The grid plan runs only causal tiles, within its VMEM limit, and the
+    wrapper's ``flash_grid_steps`` gauge reads the plan's step counts."""
+    from repro import obs
+    from repro.kernels import flash_attention as fa
+    plan = fa._train_plan(B, H, Sq, Sk, hd, q_offset, True)
+    assert H % plan.hb == 0
+    prev = obs.set_telemetry(obs.Telemetry(enabled=True))
+    try:
+        x = jax.ShapeDtypeStruct((B, H, Sq, hd), jnp.float32)
+        kv = jax.ShapeDtypeStruct((B, H, Sk, hd), jnp.float32)
+        jax.eval_shape(lambda q, k, v: fa.flash_attention_mha(
+            q, k, v, q_offset=q_offset), x, kv, kv)
+        gauge = obs.get_telemetry().metrics.get("flash_grid_steps")
+    finally:
+        obs.set_telemetry(prev)
+    for kernel in ("fwd", "dq", "dkv"):
+        total, live = plan.grid_steps(kernel)
+        assert live == total == B * (H // plan.hb) * _causal_tiles(plan)
+        assert gauge.value(kernel=kernel, live=1) == live
+        assert gauge.value(kernel=kernel, live=0) == 0
+        assert plan.vmem_bytes(kernel) <= (plan.vmem_limit or fa._SCOPED_VMEM)
+        if Sq == 2048:
+            assert total <= 1024  # 16,384 at 128 x 128 tiles, a head a step
+
+
+def test_train_plan_dead_steps_zero_fill():
+    """Under causal, KV blocks past the last query get one dead step each
+    (it writes their zero dk, dv); the forward and dq need none."""
+    from repro.kernels import flash_attention as fa
+    plan = fa._train_plan(1, 3, 48, 112, 32, 0, True, block_q=16, block_k=16)
+    assert plan.grid_steps("fwd") == plan.grid_steps("dq") == (6, 6)
+    assert plan.grid_steps("dkv") == (6 + 4, 6)  # KV blocks 3..6 see nothing
 
 
 def test_flash_attention_bf16():

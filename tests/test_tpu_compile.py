@@ -64,6 +64,19 @@ def test_flash_attention_fwd_bwd_compiles(one_chip):
     assert hlo.count("tpu_custom_call") == 3  # fwd, dq, dkv
 
 
+@pytest.mark.parametrize("hd", [64, 128])
+def test_flash_attention_train_shape_compiles(one_chip, hd):
+    """The forward, dq and dkv kernels at granite-train-2k's attention shape
+    (batch 2, 32 heads, 2048 positions), under the plan the shapes give."""
+    q = _sds(one_chip, (2, H, 2048, hd))
+
+    def loss(q, k, v):
+        return fa.flash_attention_mha(q, k, v, interpret=False).sum()
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert hlo.count("tpu_custom_call") == 3  # fwd, dq, dkv
+
+
 def test_kernel_names_are_hlo_instruction_names(one_chip, monkeypatch):
     """A kernel's ``name`` becomes its instruction's name in the compiled
     program, which is the op name a device profile keys its time by: the
