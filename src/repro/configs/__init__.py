@@ -14,6 +14,7 @@ from repro.configs.llama3p2_vision_11b import CONFIG as _llamav
 from repro.configs.deepseek_moe_16b import CONFIG as _dsmoe
 from repro.configs.deepseek_v3_671b import CONFIG as _dsv3
 from repro.configs.rwkv6_7b import CONFIG as _rwkv6
+from repro.configs.granite4_h_micro import CONFIG as _granite4h
 from repro.configs.cnn_paper import MOBILENET_V2, RESNET34, SHUFFLENET_V2
 
 # The 10 assigned architectures (dry-run + roofline cells).
@@ -30,7 +31,11 @@ PAPER_MODELS: dict[str, ModelConfig] = {
     c.name: c for c in (RESNET34, MOBILENET_V2, SHUFFLENET_V2)
 }
 
-REGISTRY: dict[str, ModelConfig] = {**ASSIGNED, **PAPER_MODELS}
+# Trained by the chip benchmark; not served (the slot engine holds no
+# recurrent state yet), so outside the dry-run and decode sets above.
+TRAIN_ONLY: dict[str, ModelConfig] = {c.name: c for c in (_granite4h,)}
+
+REGISTRY: dict[str, ModelConfig] = {**ASSIGNED, **PAPER_MODELS, **TRAIN_ONLY}
 
 
 def get_config(name: str) -> ModelConfig:

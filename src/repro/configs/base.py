@@ -30,6 +30,11 @@ class ModelConfig:
     rope_theta: float = 10000.0
     pos_embedding: str = "rope"  # rope | sinusoidal | learned | none
     max_position: int = 1 << 20
+    # Granite's multipliers; the defaults are neutral (plain llama scaling)
+    embedding_multiplier: float = 1.0  # scales the token embeddings
+    attention_multiplier: Optional[float] = None  # softmax scale; None -> 1/sqrt(head_dim)
+    residual_multiplier: float = 1.0  # scales each mixer's and MLP's output
+    logits_scaling: float = 1.0  # logits are divided by it
 
     # --- MoE ---
     n_experts: int = 0  # routed experts (0 -> dense MLP)
@@ -56,7 +61,11 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_conv_width: int = 4
     ssm_n_groups: int = 1
+    ssm_conv_bias: bool = False
     shared_attn_every: int = 0  # zamba2: apply the shared attn block every N layers
+    # per-layer mixer ("mamba" | "attention"), each followed by the MLP
+    # (granite-4.0-h); empty -> the family's own stack
+    layer_types: Tuple[str, ...] = ()
 
     # --- enc-dec (whisper) ---
     n_encoder_layers: int = 0
@@ -81,6 +90,9 @@ class ModelConfig:
     def __post_init__(self):
         if self.family != "cnn" and self.n_heads and not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.layer_types and len(self.layer_types) != self.n_layers:
+            raise ValueError(f"{len(self.layer_types)} layer_types for "
+                             f"{self.n_layers} layers")
 
     # -- derived properties ------------------------------------------------
     @property
@@ -144,6 +156,10 @@ class ModelConfig:
         if self.family == "ssm":
             per_layer = self._rwkv_params() if "rwkv" in self.name else self._ssm_params()
             total = self.n_layers * per_layer
+        elif self.layer_types:
+            mlp = self._mlp_params(self.d_ff) + 2 * d
+            total = sum(mlp + (self._ssm_params() if kind == "mamba" else self._attn_params())
+                        for kind in self.layer_types)
         elif self.family == "hybrid":
             total = self.n_layers * self._ssm_params()
             if self.shared_attn_every:
@@ -198,10 +214,13 @@ class ModelConfig:
         return total
 
     def reduced(self) -> "ModelConfig":
-        """Tiny same-family config for CPU smoke tests."""
+        """Tiny same-family config for CPU smoke tests; a layer pattern keeps
+        one layer of each kind, in the order they first appear."""
         kw = dataclasses.asdict(self)
+        kinds = tuple(dict.fromkeys(self.layer_types))
         kw.update(
-            n_layers=min(self.n_layers, 2) or self.n_layers,
+            n_layers=len(kinds) or min(self.n_layers, 2) or self.n_layers,
+            layer_types=kinds,
             d_model=min(self.d_model, 64) if self.d_model else 0,
             n_heads=min(self.n_heads, 4) if self.n_heads else 0,
             n_kv_heads=min(self.n_kv_heads, 2) if self.n_kv_heads else 0,

@@ -338,12 +338,12 @@ def _fwd_kernel(qt_ref, kt_ref, ft_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape[1:])
 
 
-def _fwd_call(q, k, v, *, plan: _Plan, interpret: bool, lowp: bool = False):
+def _fwd_call(q, k, v, *, plan: _Plan, scale: float, interpret: bool,
+              lowp: bool = False):
     B, H, Sq, hd = q.shape
     rows = (plan.hb, plan.bq, _LANES)
     return _train_call(
-        functools.partial(_fwd_kernel, plan=plan, scale=1.0 / math.sqrt(hd),
-                          lowp=lowp),
+        functools.partial(_fwd_kernel, plan=plan, scale=scale, lowp=lowp),
         plan, False, (q, k, v),
         in_specs=[_q_spec(plan), _kv_spec(plan), _kv_spec(plan)],
         out_specs=[_q_spec(plan), _q_spec(plan, _LANES)],
@@ -440,11 +440,10 @@ def _dkv_kernel(qt_ref, kt_ref, ft_ref, k_ref, v_ref, q_ref, do_ref, lse_ref,
         dv_ref[0] = dv_scr[...]
 
 
-def _bwd_call(q, k, v, o, lse, do, *, plan: _Plan, interpret: bool,
-              lowp: bool = False):
+def _bwd_call(q, k, v, o, lse, do, *, plan: _Plan, scale: float,
+              interpret: bool, lowp: bool = False):
     B, H, Sq, hd = q.shape
     Sk = k.shape[2]
-    scale = 1.0 / math.sqrt(hd)
     # Mosaic tiles a block's last two dims by (8, 128): per-row statistics
     # ride lane-broadcast (bq, 128) blocks, as the m/l scratch does
     lanes = (B, H, Sq, _LANES)
@@ -485,25 +484,32 @@ def _bwd_call(q, k, v, o, lse, do, *, plan: _Plan, interpret: bool,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_mha(q, k, v, plan, interpret, lowp):
-    o, _ = _fwd_call(q, k, v, plan=plan, interpret=interpret, lowp=lowp)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_mha(q, k, v, plan, scale, interpret, lowp):
+    o, _ = _fwd_call(q, k, v, plan=plan, scale=scale, interpret=interpret,
+                     lowp=lowp)
     return o
 
 
-def _flash_mha_fwd(q, k, v, plan, interpret, lowp):
-    o, lse = _fwd_call(q, k, v, plan=plan, interpret=interpret, lowp=lowp)
+def _flash_mha_fwd(q, k, v, plan, scale, interpret, lowp):
+    o, lse = _fwd_call(q, k, v, plan=plan, scale=scale, interpret=interpret,
+                       lowp=lowp)
     return o, (q, k, v, o, lse[..., 0])
 
 
-def _flash_mha_bwd(plan, interpret, lowp, res, do):
+def _flash_mha_bwd(plan, scale, interpret, lowp, res, do):
     q, k, v, o, lse = res
-    dq, dk, dv = _bwd_call(q, k, v, o, lse, do, plan=plan,
+    dq, dk, dv = _bwd_call(q, k, v, o, lse, do, plan=plan, scale=scale,
                            interpret=interpret, lowp=lowp)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 _flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)
+
+
+def _scale(scale: Optional[float], q) -> float:
+    """The softmax scale: as given, else ``1/sqrt(head_dim)``."""
+    return 1.0 / math.sqrt(q.shape[-1]) if scale is None else float(scale)
 
 
 def _plan_for(q, k, causal, q_offset, block_q, block_k) -> _Plan:
@@ -514,12 +520,14 @@ def _plan_for(q, k, causal, q_offset, block_q, block_k) -> _Plan:
 
 
 def flash_attention_mha(q, k, v, *, causal: bool = True, q_offset: int = 0,
+                        scale: Optional[float] = None,
                         block_q: Optional[int] = None,
                         block_k: Optional[int] = None,
                         interpret: Optional[bool] = None,
                         lowp: Optional[bool] = None):
     """q,k,v: (B,H,S,hd) same head count. Returns (B,H,Sq,hd); differentiable.
 
+    ``scale`` is the softmax scale (default ``1/sqrt(hd)``).
     ``block_q`` / ``block_k`` default to the grid plan's (``_train_plan``).
     ``interpret`` and ``lowp`` (bf16 dot inputs, REPRO_ATTN_BF16) resolve
     eagerly here — outside any jit — so env flips take effect per call.
@@ -534,8 +542,8 @@ def flash_attention_mha(q, k, v, *, causal: bool = True, q_offset: int = 0,
             f"'chunked' impl for asymmetric-head attention (e.g. MLA prefill)")
     plan = _plan_for(q, k, causal, q_offset, block_q, block_k)
     _publish_steps(plan, _KERNELS)
-    return _flash_mha(q, k, v, plan, resolve_interpret(interpret),
-                      attn_bf16(lowp))
+    return _flash_mha(q, k, v, plan, _scale(scale, q),
+                      resolve_interpret(interpret), attn_bf16(lowp))
 
 
 def flash_attention_fwd_lse(q, k, v, *, causal: bool = True, q_offset: int = 0,
@@ -546,7 +554,7 @@ def flash_attention_fwd_lse(q, k, v, *, causal: bool = True, q_offset: int = 0,
     """Forward that also returns the (B,H,Sq) log-sum-exp residual rows."""
     plan = _plan_for(q, k, causal, q_offset, block_q, block_k)
     _publish_steps(plan, ("fwd",))
-    o, lse = _fwd_call(q, k, v, plan=plan,
+    o, lse = _fwd_call(q, k, v, plan=plan, scale=_scale(None, q),
                        interpret=resolve_interpret(interpret),
                        lowp=attn_bf16(lowp))
     return o, lse[..., 0]

@@ -32,8 +32,10 @@ def depthwise_conv(x, w):
     return _dw(x, w)
 
 
-def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0):
-    """q: (B,Sq,H,hd); k,v: (B,Sk,K,hd) with K dividing H (GQA broadcast).
+def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
+                    scale=None):
+    """q: (B,Sq,H,hd); k,v: (B,Sk,K,hd) with K dividing H (GQA broadcast);
+    ``scale`` is the softmax scale (default ``1/sqrt(hd)``).
 
     The head broadcast is a reshape of a broadcast_to — its transpose is a
     sum over the query-head group axis, which is exactly how dk/dv for a
@@ -52,7 +54,8 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0):
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    out = flash_attention_mha(qt, kt, vt, causal=causal, q_offset=q_offset)
+    out = flash_attention_mha(qt, kt, vt, causal=causal, q_offset=q_offset,
+                              scale=scale)
     return out.transpose(0, 2, 1, 3)
 
 

@@ -79,8 +79,10 @@ def _group(q, n_kv):
     return q.reshape(B, S, n_kv, H // n_kv, hd)
 
 
-def naive_attention(q, k, v, *, causal: bool, q_offset=0, kv_len: Optional[jax.Array] = None):
-    """Oracle attention. q:(B,Sq,H,hd) k,v:(B,Sk,K,hd).
+def naive_attention(q, k, v, *, causal: bool, q_offset=0, kv_len: Optional[jax.Array] = None,
+                    scale: Optional[float] = None):
+    """Oracle attention. q:(B,Sq,H,hd) k,v:(B,Sk,K,hd); ``scale`` is the
+    softmax scale (default ``1/sqrt(hd)``).
 
     ``kv_len`` may be a scalar or a per-sequence (B,) vector (ragged decode
     under continuous batching); rows must keep kv_len >= 1 to stay
@@ -92,7 +94,7 @@ def naive_attention(q, k, v, *, causal: bool, q_offset=0, kv_len: Optional[jax.A
     B, Sq, H, hd = q.shape
     K = k.shape[2]
     qg = _group(q, K)
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     scores = jnp.einsum("bqkgh,bskh->bkgqs", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     Sk = k.shape[1]
@@ -118,7 +120,8 @@ def naive_attention(q, k, v, *, causal: bool, q_offset=0, kv_len: Optional[jax.A
     return out.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
 
-def chunked_attention(q, k, v, *, causal: bool, q_offset=0, chunk: int = 1024):
+def chunked_attention(q, k, v, *, causal: bool, q_offset=0, chunk: int = 1024,
+                      scale: Optional[float] = None):
     """Flash-style online-softmax attention, scanning KV chunks.
 
     Never materializes the (Sq, Sk) score matrix; per-step live memory is
@@ -145,7 +148,8 @@ def chunked_attention(q, k, v, *, causal: bool, q_offset=0, chunk: int = 1024):
     kc = jnp.moveaxis(k.reshape(B, n_chunks, chunk, K, hd), 1, 0)
     vc = jnp.moveaxis(v.reshape(B, n_chunks, chunk, K, vd), 1, 0)
 
-    qh = q.transpose(0, 2, 1, 3).astype(jnp.float32) / math.sqrt(hd)  # (B,H,Sq,hd)
+    qh = q.transpose(0, 2, 1, 3).astype(jnp.float32)  # (B,H,Sq,hd)
+    qh = qh / math.sqrt(hd) if scale is None else qh * scale
     qh = shard(qh, "batch", "tp", None, None)
     q_idx = jnp.arange(Sq) + q_offset
 
@@ -195,17 +199,20 @@ def chunked_attention(q, k, v, *, causal: bool, q_offset=0, chunk: int = 1024):
     return out.astype(q.dtype)
 
 
-def attention_impl(q, k, v, *, causal, q_offset=0, impl: str = "chunked", chunk: int = 1024):
+def attention_impl(q, k, v, *, causal, q_offset=0, impl: str = "chunked", chunk: int = 1024,
+                   scale: Optional[float] = None):
+    """Softmax attention by ``impl``; ``scale`` defaults to 1/sqrt(hd)."""
     if impl == "naive":
-        return naive_attention(q, k, v, causal=causal, q_offset=q_offset)
+        return naive_attention(q, k, v, causal=causal, q_offset=q_offset, scale=scale)
     if impl == "chunked":
-        return chunked_attention(q, k, v, causal=causal, q_offset=q_offset, chunk=chunk)
+        return chunked_attention(q, k, v, causal=causal, q_offset=q_offset, chunk=chunk,
+                                 scale=scale)
     if impl == "pallas":
         from repro.kernels import ops as kops
 
         def kernel(q, k, v):
             return kops.flash_attention(q, k, v, causal=causal,
-                                        q_offset=q_offset)
+                                        q_offset=q_offset, scale=scale)
 
         # XLA cannot partition a Mosaic kernel: under a mesh, run it per
         # shard, split over batch and KV heads (whole GQA groups per shard)
@@ -237,7 +244,8 @@ def gqa_forward(p, x, cfg, *, positions=None, kv_x=None, causal=True,
     if positions is not None and kv_x is None and cfg.pos_embedding == "rope":
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
-    out = attention_impl(q, k, v, causal=causal and kv_x is None, impl=impl, chunk=chunk)
+    out = attention_impl(q, k, v, causal=causal and kv_x is None, impl=impl, chunk=chunk,
+                         scale=cfg.attention_multiplier)
     out = shard(out, "batch", "seq", "tp", None)
     y = out.reshape(B, S, cfg.n_heads * hd) @ p["wo"]
     if return_kv:

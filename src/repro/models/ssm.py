@@ -19,6 +19,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models.common import dense_init
 
 # ---------------------------------------------------------------------------
@@ -26,6 +27,7 @@ from repro.models.common import dense_init
 # ---------------------------------------------------------------------------
 
 MAMBA_HEAD_DIM = 64
+SSD_CHUNK = 256  # positions per chunk of the SSD scan (mamba_chunk_size)
 
 
 def mamba2_dims(cfg):
@@ -41,7 +43,7 @@ def mamba2_params(key, cfg, dtype=jnp.float32):
     d_inner, hd, nh, g, N, conv_ch = mamba2_dims(cfg)
     d_in_proj = 2 * d_inner + 2 * g * N + nh
     ks = jax.random.split(key, 3)
-    return {
+    p = {
         "w_in": dense_init(ks[0], cfg.d_model, d_in_proj, dtype),
         "w_conv": (jax.random.normal(ks[1], (cfg.ssm_conv_width, conv_ch)) * 0.1).astype(dtype),
         "A_log": jnp.zeros((nh,), dtype),
@@ -50,13 +52,16 @@ def mamba2_params(key, cfg, dtype=jnp.float32):
         "norm_scale": jnp.ones((d_inner,), dtype),
         "w_out": dense_init(ks[2], d_inner, cfg.d_model, dtype),
     }
+    if cfg.ssm_conv_bias:
+        p["b_conv"] = jnp.zeros((conv_ch,), dtype)
+    return p
 
 
-def _causal_conv1d(u, w):
-    """Depthwise causal conv. u: (B,S,C), w: (W,C)."""
+def _causal_conv1d(u, w, b=None):
+    """Depthwise causal conv. u: (B,S,C), w: (W,C), b: (C,) or None."""
     W = w.shape[0]
     pad = jnp.pad(u, ((0, 0), (W - 1, 0), (0, 0)))
-    out = jnp.zeros_like(u)
+    out = jnp.zeros_like(u) if b is None else jnp.broadcast_to(b, u.shape)
     for i in range(W):
         out = out + pad[:, i:i + u.shape[1], :] * w[i]
     return out
@@ -89,13 +94,14 @@ def _mamba_out(p, y, z, cfg):
     return y @ p["w_out"]
 
 
-def mamba2_forward(p, x, cfg, chunk: int = 256, return_state: bool = False):
-    """Chunked SSD scan. x: (B,S,d) -> (B,S,d)."""
+def mamba2_forward(p, x, cfg, chunk: int = SSD_CHUNK, return_state: bool = False):
+    """Chunked SSD scan. x: (B,S,d) -> (B,S,d). Sets the
+    ``ssd_chunks{chunk}`` gauge (chunks a call scans) when it traces."""
     B_, S, _ = x.shape
     d_inner, hd, nh, g, N, conv_ch = mamba2_dims(cfg)
     hpg = nh // g
     z, xBC_raw, dt_raw = _mamba_project(p, x, cfg)
-    xBC = jax.nn.silu(_causal_conv1d(xBC_raw, p["w_conv"]))
+    xBC = jax.nn.silu(_causal_conv1d(xBC_raw, p["w_conv"], p.get("b_conv")))
     xs, Bm, Cm = _mamba_split(xBC, cfg, B_, S)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
@@ -103,6 +109,8 @@ def mamba2_forward(p, x, cfg, chunk: int = 256, return_state: bool = False):
 
     L = min(chunk, S)
     n_chunks = -(-S // L)
+    obs.get_telemetry().metrics.gauge(
+        "ssd_chunks", "chunks one Mamba2 call scans").labels(chunk=L).set(n_chunks)
     pad = n_chunks * L - S
     if pad:
         xs = jnp.pad(xs, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -177,6 +185,8 @@ def mamba2_decode(p, x, state, cfg):
     xBC_t = xBC_raw[:, 0]
     conv_buf = jnp.concatenate([state["conv"].astype(xBC_t.dtype), xBC_t[:, None]], axis=1)
     conv_out = jnp.einsum("bwc,wc->bc", conv_buf, p["w_conv"])
+    if "b_conv" in p:
+        conv_out = conv_out + p["b_conv"]
     xBC = jax.nn.silu(conv_out)[:, None]
     xs, Bm, Cm = _mamba_split(xBC, cfg, B_, 1)
     dt = jax.nn.softplus(dt_raw[:, 0].astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))

@@ -3,16 +3,20 @@
 Layers are stacked along a leading axis and driven by ``lax.scan`` so the HLO
 (and compile time) is independent of depth; non-uniform structure (first-k
 dense MoE layers, cross-attn every Nth block, zamba's shared block) is handled
-by scanning over uniform *groups*.
+by scanning over uniform *groups*. A layer pattern (``cfg.layer_types``,
+granite-4.0-h) keeps each run of consecutive layers of one kind as one stack
+under ``params["runs"]`` and scans the runs in order.
 """
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import ssm
 from repro.models.attention import (attn_params, gqa_decode, gqa_decode_paged,
                                     gqa_decode_spec, gqa_decode_spec_paged,
@@ -44,10 +48,14 @@ def _maybe_remat(fn, remat: str):
 # ---------------------------------------------------------------------------
 
 
-def init_dense_layer(key, cfg, dtype, moe: bool = False, d_ff: Optional[int] = None):
+def init_dense_layer(key, cfg, dtype, moe: bool = False, d_ff: Optional[int] = None,
+                     mixer: str = "attention"):
     k1, k2 = jax.random.split(key)
-    p = {"ln1": norm_params(cfg, dtype), "ln2": norm_params(cfg, dtype),
-         "attn": attn_params(k1, cfg, dtype)}
+    p = {"ln1": norm_params(cfg, dtype), "ln2": norm_params(cfg, dtype)}
+    if mixer == "mamba":
+        p["mamba"] = ssm.mamba2_params(k1, cfg, dtype)
+    else:
+        p["attn"] = attn_params(k1, cfg, dtype)
     if moe:
         p["moe"] = moe_params(k2, cfg, dtype)
     else:
@@ -94,11 +102,22 @@ def init_shared_attn_block(key, cfg, dtype):
 # ---------------------------------------------------------------------------
 
 
+def _residual(h, y, cfg):
+    """``h + y``, the branch scaled by Granite's residual multiplier where it
+    is not neutral."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return shard(h + y, "batch", "seq", None)
+
+
 def dense_block(p, h, cfg, positions, impl, chunk, return_kv=False, moe_cf=1.25):
-    """Standard pre-norm block. Returns (h, aux[, kv_cache_entry])."""
+    """Standard pre-norm block: a mixer (attention, or Mamba2 in a layer
+    pattern), then the MLP or MoE. Returns (h, aux[, kv_cache_entry])."""
     x = apply_norm(p["ln1"], h, cfg.norm)
     kv = None
-    if cfg.use_mla:
+    if "mamba" in p:
+        a = ssm.mamba2_forward(p["mamba"], x, cfg)
+    elif cfg.use_mla:
         if return_kv:
             a, kv = mla_forward(p["attn"], x, cfg, positions=positions, impl=impl,
                                 chunk=chunk, return_cache=True)
@@ -110,13 +129,13 @@ def dense_block(p, h, cfg, positions, impl, chunk, return_kv=False, moe_cf=1.25)
                                 chunk=chunk, return_kv=True)
         else:
             a = gqa_forward(p["attn"], x, cfg, positions=positions, impl=impl, chunk=chunk)
-    h = shard(h + a, "batch", "seq", None)
+    h = _residual(h, a, cfg)
     x = apply_norm(p["ln2"], h, cfg.norm)
     if "moe" in p:
         m, aux = apply_moe(p["moe"], x, cfg, capacity_factor=moe_cf)
     else:
         m, aux = apply_mlp(p["mlp"], x, cfg.activation), jnp.zeros((), jnp.float32)
-    h = shard(h + m, "batch", "seq", None)
+    h = _residual(h, m, cfg)
     return (h, aux, kv) if return_kv else (h, aux)
 
 
@@ -172,7 +191,12 @@ def init_decoder(key, cfg, dtype=jnp.float32):
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(keys[1], cfg.d_model, cfg.vocab_size, dtype)
 
-    if cfg.family == "ssm":
+    if cfg.layer_types:
+        params["runs"] = [
+            jax.vmap(lambda k, kind=kind: init_dense_layer(k, cfg, dtype, mixer=kind))(
+                jax.random.split(jax.random.fold_in(keys[2], i), n))
+            for i, (kind, n) in enumerate(layer_runs(cfg.layer_types))]
+    elif cfg.family == "ssm":
         lkeys = jax.random.split(keys[2], cfg.n_layers)
         params["layers"] = jax.vmap(lambda k: init_rwkv_layer(k, cfg, dtype))(lkeys)
     elif cfg.family == "hybrid":
@@ -211,6 +235,24 @@ def init_decoder(key, cfg, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 
 
+def layer_runs(layer_types):
+    """(kind, count) of each run of consecutive layers of one kind."""
+    return [(kind, len(list(run))) for kind, run in itertools.groupby(layer_types)]
+
+
+def _publish_layers(runs) -> None:
+    """Set the ``hybrid_layers{kind}`` gauge: the layers of each kind the
+    model's parameter runs hold."""
+    held = {}
+    for p in runs:
+        kind = "mamba" if "mamba" in p else "attention"
+        held[kind] = held.get(kind, 0) + p["ln1"]["scale"].shape[0]
+    gauge = obs.get_telemetry().metrics.gauge(
+        "hybrid_layers", "layers of each kind the model holds")
+    for kind, n in held.items():
+        gauge.labels(kind=kind).set(n)
+
+
 def _logits(params, cfg, h):
     h = apply_norm(params["ln_f"], h, cfg.norm)
     if cfg.tie_embeddings:
@@ -221,6 +263,8 @@ def _logits(params, cfg, h):
     else:
         w = params["lm_head"]
     logits = h @ w.astype(h.dtype)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return shard(logits, "batch", "seq", "tp")
 
 
@@ -230,11 +274,24 @@ def forward_decoder(params, cfg, tokens, *, image_embed=None, audio_embed=None,
     """Returns (logits, aux) or (logits, aux, cache) when return_cache."""
     B, S = tokens.shape
     h = embed_tokens(params["embed"], tokens)
+    if cfg.embedding_multiplier != 1.0:
+        h = h * cfg.embedding_multiplier
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     aux = jnp.zeros((), jnp.float32)
     caches = None
 
-    if cfg.family == "ssm":
+    if cfg.layer_types:
+        assert not return_cache, "a layer pattern has no serving path"
+        _publish_layers(params["runs"])
+        block = _maybe_remat(functools.partial(
+            dense_block, cfg=cfg, positions=positions, impl=impl, chunk=chunk), remat)
+
+        def run_body(hh, lp):
+            return block(lp, hh)[0], None
+
+        for run in params["runs"]:
+            h, _ = jax.lax.scan(run_body, h, run)
+    elif cfg.family == "ssm":
         assert not return_cache, "use prefill_decoder for SSM caches"
         block = _maybe_remat(functools.partial(rwkv_block, cfg=cfg), remat)
 
@@ -348,6 +405,7 @@ def prefill_decoder(params, cfg, tokens, *, image_embed=None, audio_embed=None,
     ``last_pos`` supports bucketed admission: prompts padded up to a bucket
     length still report the logits of their true last token.
     """
+    _check_served(cfg)
     if cfg.family not in ("ssm", "hybrid"):
         logits, aux, (h, caches) = forward_decoder(
             params, cfg, tokens, image_embed=image_embed, audio_embed=audio_embed,
@@ -432,7 +490,18 @@ def loss_decoder(params, cfg, batch, *, impl="chunked", chunk=1024, remat="none"
 # ---------------------------------------------------------------------------
 
 
+def _check_served(cfg) -> None:
+    """Refuse to serve what the cached paths do not compute: a layer
+    pattern, or Granite's multipliers (the training path runs both)."""
+    neutral = (cfg.embedding_multiplier == 1.0 and cfg.residual_multiplier == 1.0
+               and cfg.logits_scaling == 1.0 and cfg.attention_multiplier is None)
+    if cfg.layer_types or not neutral:
+        raise ValueError(f"{cfg.name}: the serving paths run no layer pattern "
+                         f"and no Granite multipliers")
+
+
 def init_cache_decoder(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
+    _check_served(cfg)
     if cfg.family == "ssm":
         st = ssm.init_rwkv_state(cfg, batch, dtype)
         return jax.tree_util.tree_map(
@@ -467,6 +536,7 @@ def init_paged_cache_decoder(cfg, num_blocks: int, block_size: int,
     d) pools with a leading layer axis. One block-table row addresses the same
     physical block index in every layer's pool, so the table is shared
     across the stack."""
+    _check_served(cfg)
     if cfg.family not in ("dense", "moe"):
         raise ValueError(f"paged KV cache needs a slotted-KV family, "
                          f"got {cfg.family!r}")

@@ -187,3 +187,32 @@ def test_mesh_train_step_compiles_with_pallas_attention(topo, monkeypatch):
     with jax.set_mesh(mesh):
         hlo = rung.jitted_step(cfg, opt).lower(state, batch).compile().as_text()
     assert hlo.count("tpu_custom_call") == 3
+
+
+def test_hybrid_train_step_compiles_at_the_cell_shape(one_chip, monkeypatch):
+    """granite4h-train-8k's step: one period of granite-4.0-h-micro (5
+    Mamba2, 1 attention, 4 Mamba2 layers), the 12,544-row vocabulary slice,
+    1 x 8192 tokens, full remat, f32 weights and Adam. The compiler refuses
+    a step that does not fit the chip's memory."""
+    from repro.configs import get_config
+    from repro.engine.rungs import Rung
+    from repro.launch.steps import init_train_state
+    from repro.optim.optimizers import adam
+
+    monkeypatch.setattr("repro.kernels.backend.default_interpret",
+                        lambda: False)
+    full = get_config("granite-4.0-h-micro")
+    cfg = dataclasses.replace(full, n_layers=10, layer_types=full.layer_types[:10],
+                              vocab_size=12544)
+    rung, opt = Rung(name="r", attn_impl="pallas", remat="full"), adam()
+    state = jax.eval_shape(lambda: init_train_state(
+        rung.build_model(cfg), opt, jax.random.PRNGKey(0)))
+    state = jax.tree_util.tree_map(
+        lambda a: _sds(one_chip, a.shape, a.dtype), state)
+    batch = {"tokens": _sds(one_chip, (1, 8192), jnp.int32)}
+    compiled = rung.jitted_step(cfg, opt).lower(state, batch).compile()
+    train = compiled.as_text()
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert re.search(rf"%{name}\.\d+ = .*tpu_custom_call", train), name
+    # weights, Adam's m and v: 772.2M parameters x 12 bytes
+    assert compiled.memory_analysis().argument_size_in_bytes > 9.2e9
